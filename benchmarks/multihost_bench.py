@@ -25,17 +25,18 @@ Two sections, written to ``BENCH_multihost.json``:
 The whole benchmark doubles as a hang canary for the transport threads
 (per-connection reader/writer, heartbeat monitor, node frame loop) when
 CI runs it under a hard wall-clock timeout.
+
+It is a CPU check: every process runs with ``JAX_PLATFORMS=cpu`` (node
+processes never take a chip), so its seconds are CPU seconds.
 """
 
 from __future__ import annotations
 
 import os
-import shutil
 import sys
-import tempfile
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _TESTS = os.path.join(_REPO, "tests")
@@ -61,7 +62,9 @@ def bench_multihost(quick: bool = False, strict: bool = False) -> dict:
     from repro.core import ContextMode, PCMManager
     from repro.cluster.node import spawn_node_process
 
-    aot_dir = tempfile.mkdtemp(prefix="pcm-aot-cache-")
+    from repro.launch.compile_cache import AOT_SUBDIR, configure_compile_cache
+
+    aot_dir = os.path.join(configure_compile_cache(), AOT_SUBDIR)
     mgr = PCMManager(mode=ContextMode.FULL, n_workers=0,
                      chunk_bytes=1 << 20)
     procs = {}
@@ -166,7 +169,6 @@ def bench_multihost(quick: bool = False, strict: bool = False) -> dict:
                 p.wait(timeout=10)
             except Exception:
                 pass
-        shutil.rmtree(aot_dir, ignore_errors=True)
 
 
 if __name__ == "__main__":

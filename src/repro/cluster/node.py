@@ -565,11 +565,16 @@ def run(argv: Optional[List[str]] = None) -> int:
         from repro.serving.engine import set_aot_cache_dir
         set_aot_cache_dir(args.aot_cache)
 
+    import jax
+    from repro.core.store import device_tier_bytes
     from repro.core.transport import Connection
     profile = None
     if args.profile:
         from repro.cluster.devices import PROFILES
-        profile = PROFILES.get(args.profile)
+        if args.profile not in PROFILES:
+            raise ValueError(f"unknown --profile {args.profile!r}; known: "
+                             f"{', '.join(sorted(PROFILES))}")
+        profile = PROFILES[args.profile]
 
     host_str, _, port_str = args.connect.rpartition(":")
     sock = socket.create_connection((host_str, int(port_str)), timeout=10)
@@ -582,7 +587,8 @@ def run(argv: Optional[List[str]] = None) -> int:
     # HELLO is queued BEFORE the writer starts so it is provably the
     # first frame out — the manager's accept thread expects it and would
     # reject a heartbeat arriving first
-    conn.send("hello", {"worker_id": args.worker_id, "pid": os.getpid()},
+    conn.send("hello", {"worker_id": args.worker_id, "pid": os.getpid(),
+                        "device_bytes": device_tier_bytes(jax.devices()[0])},
               pickle.dumps(profile, _PICKLE))
     conn.start()
     try:
@@ -603,7 +609,20 @@ def spawn_node_process(address, worker_id: str,
     """Launch one worker node as a subprocess pointed at a manager's
     ``listen()`` address. PYTHONPATH is extended with this repro package's
     source root plus ``extra_path`` (where module-level recipe builders
-    live), so the child can unpickle everything the manager sends."""
+    live), so the child can unpickle everything the manager sends.
+
+    Node processes run on the CPU only, and the child's environment must
+    say so (``JAX_PLATFORMS=cpu``): a chip belongs to one process, so a
+    child would hang on a chip its parent holds, or two children would
+    each reach for every chip. Chips are driven from one process, where
+    each PCMManager live worker takes a device of its own."""
+    child_env = dict(os.environ if env is None else env)
+    if child_env.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        raise RuntimeError(
+            "worker node processes run on the CPU only: set "
+            "JAX_PLATFORMS=cpu in the node's environment, or drive the "
+            "chips from one process with PCMManager live workers (one "
+            "device each)")
     import repro
     # repro is a namespace package (no __init__.py): derive the source
     # root from __path__, not __file__
@@ -623,7 +642,6 @@ def spawn_node_process(address, worker_id: str,
         cmd += ["--spill-dir", spill_dir]
     for p in extra_path:
         cmd += ["--path", str(p)]
-    child_env = dict(os.environ if env is None else env)
     parts = [src_root] + [str(p) for p in extra_path]
     if child_env.get("PYTHONPATH"):
         parts.append(child_env["PYTHONPATH"])

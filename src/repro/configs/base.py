@@ -293,6 +293,7 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         vision_dim=32 if cfg.vision_dim else 0,
         param_dtype="float32",
         compute_dtype="float32",
+        kv_cache_dtype="float32",
     )
     # keep layer pattern divisibility
     if cfg.family == "hybrid" and cfg.shared_attn_every:

@@ -36,6 +36,7 @@ interchangeably with the simulator-backed dry-run backend.
 from __future__ import annotations
 
 import atexit
+import collections
 import itertools
 import pickle
 import queue
@@ -46,6 +47,7 @@ import traceback
 import weakref
 from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.core import wire as pcm_wire
@@ -55,8 +57,9 @@ from repro.core.context import (GB, ContextRecipe, ContextSnapshot,
 from repro.core.library import Library
 from repro.core.scheduler import (Action, ContextAwareScheduler, ContextMode,
                                   Task)
-from repro.core.store import (ContextStore, SnapshotPool, Tier,
-                              TierFullError)
+from repro.core.store import (DEFAULT_DEVICE_BYTES, ContextStore,
+                              SnapshotPool, Tier, TierFullError,
+                              device_tier_bytes)
 from repro.core.streaming import (ChunkCorruptionError, ChunkPlan, ChunkRef,
                                   StripeBuffer, assign_lanes, chunk_digest)
 from repro.core.transfer import FetchSource, TransferPlan, TransferPlanner
@@ -223,16 +226,24 @@ class LiveWorker:
     the in-flight result is discarded at the revalidation barrier and the
     retirement demotion runs right after the current message finishes —
     no state is ever snapshotted mid-mutation.
+
+    The worker runs on one device (``device``): its whole thread runs
+    under ``jax.default_device``, so every context it builds, restores or
+    invokes — weights, caches, transfers, executables — lands there. Its
+    DEVICE tier holds what that device holds, unless a DeviceProfile
+    says otherwise.
     """
 
-    def __init__(self, worker_id: str, manager: "PCMManager", profile=None):
+    def __init__(self, worker_id: str, manager: "PCMManager", profile=None,
+                 device=None):
         self.worker_id = worker_id
         self.profile = profile          # cluster.devices.DeviceProfile
+        self.device = device or jax.local_devices()[0]
         self.library = Library(worker_id, snapshots=manager.snapshots,
                                streamed=manager.streamed)
         hbm_gb = getattr(profile, "hbm_gb", None)
-        self.store = ContextStore(device_bytes=int(hbm_gb * GB)) \
-            if hbm_gb else ContextStore()
+        self.store = ContextStore(device_bytes=int(hbm_gb * GB) if hbm_gb
+                                  else device_tier_bytes(self.device))
         self.mailbox: "queue.SimpleQueue" = queue.SimpleQueue()
         self.alive = True
         self._mgr = manager
@@ -250,6 +261,10 @@ class LiveWorker:
 
     # ------------------------------------------------------------ thread ---
     def _run(self):
+        with jax.default_device(self.device):
+            self._loop()
+
+    def _loop(self):
         while True:
             msg = self.mailbox.get()
             kind = msg[0]
@@ -927,15 +942,17 @@ class RemoteWorker:
 
     is_remote = True
 
-    def __init__(self, worker_id: str, manager: "PCMManager", profile=None):
+    def __init__(self, worker_id: str, manager: "PCMManager", profile=None,
+                 device_bytes: int = DEFAULT_DEVICE_BYTES):
         self.worker_id = worker_id
         self.profile = profile
         self._mgr = manager
         self.conn: Optional[Connection] = None     # set before start
         self.library = _RemoteLibraryMirror(worker_id, self._send)
         hbm_gb = getattr(profile, "hbm_gb", None)
-        self.store = ContextStore(device_bytes=int(hbm_gb * GB)) \
-            if hbm_gb else ContextStore()
+        # without a profile the node reports its own device's limit
+        self.store = ContextStore(device_bytes=int(hbm_gb * GB) if hbm_gb
+                                  else device_bytes)
         self.alive = True
         self._tokens = itertools.count()
         self._pending: Dict[int, tuple] = {}
@@ -1536,7 +1553,8 @@ class PCMManager:
             wid = worker_id or f"live{next(self._ids):03d}"
             if wid in self.workers:
                 raise ValueError(f"worker {wid!r} already exists")
-            w = LiveWorker(wid, self, profile=profile)
+            w = LiveWorker(wid, self, profile=profile,
+                           device=self._free_device())
             w.store.pinned.update(self._pinned)
             w.library.pinned.update(self._pinned)
             self.workers[wid] = w
@@ -1548,6 +1566,22 @@ class PCMManager:
             self._dispatch(acts)
             self._cond.notify_all()
             return wid
+
+    def _free_device(self):
+        """The device a new live worker runs on: the local device that
+        the fewest live in-process workers hold (lowest id first), so N
+        workers on N chips take one chip each. CPU devices are shared
+        freely, but an accelerator holds one worker: a worker's DEVICE
+        tier is the whole chip, so a second one would admit its memory
+        twice."""
+        held = collections.Counter(w.device for w in self.workers.values()
+                                   if isinstance(w, LiveWorker))
+        dev = min(jax.local_devices(), key=lambda d: (held[d], d.id))
+        if held[dev] and dev.platform != "cpu":
+            raise RuntimeError(
+                f"all {len(held)} local {dev.platform} devices already run "
+                "a live worker, and a chip holds one")
+        return dev
 
     def preempt_worker(self, worker_id: str):
         """No-warning device reclaim. The scheduler requeues the worker's
@@ -1607,7 +1641,9 @@ class PCMManager:
             "chunk_bytes": self.chunk_bytes,
             "export_chunk_budget": self.export_chunk_budget,
             "pinned": sorted(self._pinned)})
-        w = RemoteWorker(wid, self, profile=profile)
+        w = RemoteWorker(wid, self, profile=profile,
+                         device_bytes=meta.get("device_bytes",
+                                               DEFAULT_DEVICE_BYTES))
         conn = Connection(
             sock, f"node-{wid}", on_frame=w._on_frame,
             on_lost=lambda _c, reason: self._remote_lost(w, reason),

@@ -155,11 +155,29 @@ class _Entry:
     last_used: float = field(default_factory=time.monotonic)
 
 
+DEFAULT_DEVICE_BYTES = 24 * GB
+
+
+def device_tier_bytes(device) -> int:
+    """DEVICE-tier capacity of a worker on ``device``: its allocator's own
+    limit. The CPU reports none and keeps the default; an accelerator that
+    reports none is refused, since a guess could over-commit the chip
+    instead of evicting."""
+    if device.platform == "cpu":
+        return DEFAULT_DEVICE_BYTES
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        raise RuntimeError(
+            f"cannot read the memory limit of {device} "
+            f"({device.device_kind}); give the worker a DeviceProfile")
+    return int(limit)
+
+
 class ContextStore:
     """Tracks which context keys are resident at which tier of one worker."""
 
     def __init__(self, disk_bytes: int = 70 * GB, host_bytes: int = 10 * GB,
-                 device_bytes: int = 24 * GB):
+                 device_bytes: int = DEFAULT_DEVICE_BYTES):
         self.capacity = {Tier.LOCAL_DISK: disk_bytes,
                          Tier.HOST_RAM: host_bytes,
                          Tier.DEVICE: device_bytes}

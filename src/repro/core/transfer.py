@@ -466,7 +466,10 @@ class TransferPlanner:
                 self.completed_flows += 1
         if failed:
             return
+        # a recipe that declares no transfer bytes prices its peer rung
+        # without a rate, and a rate of 0 B/s would poison every later one
         if measured_seconds is not None and measured_seconds > 0 \
+                and plan.nbytes > 0 \
                 and plan.fetch_source in (FetchSource.PEER, FetchSource.FS):
             path = f"p2p:{getattr(plan, 'kind', 'memcpy')}" \
                 if plan.p2p else "fs"

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _gemm_kernel(x_ref, w_ref, o_ref, acc_scr, *, nk: int):
@@ -55,7 +54,7 @@ def grouped_gemm(x, w, *, block_c: int = 128, block_f: int = 128,
         out_specs=pl.BlockSpec((1, bc, bf), lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, C, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

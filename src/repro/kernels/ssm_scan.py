@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _ssd_kernel(q_ref, k_ref, v_ref, la_ref, y_ref, state_out_ref, state_scr,
@@ -93,7 +92,7 @@ def ssd_scan_bhs(q, k, v, log_a, *, chunk: int = 128,
             jax.ShapeDtypeStruct((BH, Dk, Dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((Dk, Dv), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, log_a)

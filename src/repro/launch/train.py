@@ -13,6 +13,7 @@ import jax
 
 from repro.configs import SHAPES, get_config, get_reduced_config
 from repro.data import PipelineConfig, batches
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import build_model
 from repro.train import LoopConfig, OptimizerConfig, train
 
@@ -32,6 +33,7 @@ def main():
                     help="use the full (not reduced) architecture config")
     args = ap.parse_args()
 
+    configure_compile_cache()
     cfg = (get_config(args.arch) if args.full_config
            else get_reduced_config(args.arch))
     model = build_model(cfg)

@@ -330,14 +330,13 @@ def attend_decode(p, x, cfg, *, cache_k, cache_v, lengths,
     else:
         valid = pos[None, :] <= lengths[:, None]
     scale = 1.0 / math.sqrt(hd)
-    if (cfg.use_kernels
-            and getattr(cfg, "gqa_decode", "grouped") != "repeat"
-            and (s_cache <= 512 or s_cache % 512 == 0)):
+    if cfg.use_kernels and getattr(cfg, "gqa_decode", "grouped") != "repeat":
         # length-masked Pallas flash-decode: per-slot work is proportional
         # to that slot's valid KV length, so the engine megastep's free
         # slots (length 0/1) skip essentially every KV block. The softmax
         # is permutation-invariant over the valid KV set, so the same call
-        # covers SWA ring buffers (n_valid caps at the window).
+        # covers SWA ring buffers (n_valid caps at the window). The kernel
+        # takes every cache length (its block divides the cache).
         from repro.kernels import ops as kops
         out = kops.flash_decode(q[:, 0], cache_k, cache_v, n_valid,
                                 scale=scale)[:, None]

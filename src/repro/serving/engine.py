@@ -152,56 +152,115 @@ NO_TOKEN = -1  # stop-table padding: never matches a real (>= 0) token id
 # ``stats.aot_cache_hits``; only a genuine XLA lowering+compile counts
 # under ``stats.compiles`` — which is what keeps the zero-recompile
 # guarantee assertable over the wire.
+#
+# A loaded executable is bound to one device, so the fingerprint names the
+# device (``<portable>@<device id>``). The portable half names everything
+# else, the device kind included: a single-device program compiled for one
+# chip is the same binary on every chip of its kind, so a payload — from
+# disk, or serialized from an executable another device loaded — is
+# loaded onto the receiver's own device with only its device assignment
+# changed. A receiver on another chip thus hits instead of compiling.
 # ---------------------------------------------------------------------------
 _AOT_EXES: "collections.OrderedDict[Tuple[str, str], Callable]" = \
     collections.OrderedDict()
 _AOT_EXES_MAX = 512
 _AOT_LOCK = threading.Lock()
-_AOT_CACHE_DIR: Optional[str] = os.environ.get("REPRO_AOT_CACHE") or None
+_AOT_CACHE_DIR: Optional[str] = None
 
 
 def set_aot_cache_dir(path: Optional[str]) -> Optional[str]:
     """Point the cross-process executable cache at ``path`` (None disables
-    it). Returns the previous setting. Worker node processes inherit the
-    same directory via ``--aot-cache`` / ``REPRO_AOT_CACHE`` so a receiver
-    re-lowers into a cache hit instead of compiling."""
+    it). Returns the previous setting. ``repro.launch.compile_cache`` sets
+    it beside JAX's persistent cache; worker node processes are pointed at
+    the same directory via ``--aot-cache`` so a receiver re-lowers into a
+    cache hit instead of compiling."""
     global _AOT_CACHE_DIR
     prev = _AOT_CACHE_DIR
     _AOT_CACHE_DIR = path
     return prev
 
 
+def _portable(fingerprint: str) -> str:
+    return fingerprint.partition("@")[0]
+
+
 def _aot_disk_file(fingerprint: str, key: str) -> Optional[str]:
     if _AOT_CACHE_DIR is None:
         return None
-    name = hashlib.sha256(f"{fingerprint}|{key}".encode()).hexdigest()[:40]
+    name = hashlib.sha256(
+        f"{_portable(fingerprint)}|{key}".encode()).hexdigest()[:40]
     return os.path.join(_AOT_CACHE_DIR, f"{name}.pcmexe")
 
 
-def _aot_cache_lookup(fingerprint: str, key: str) -> Optional[Callable]:
-    """Process-dict hit first, then the serialized on-disk payload. Any
-    failure to load/deserialize (foreign jaxlib, torn write) is a miss —
-    the caller compiles for real and republishes."""
+def _load_on(payload, device):
+    """Load a ``serialize_executable`` payload onto ``device``, whichever
+    device of the same kind it was compiled for."""
+    import io
+    from jax._src import compiler
+    from jax.experimental import serialize_executable as se
+
+    class _OnDevice(se._JaxPjrtUnpickler):
+        def persistent_load(self, pid):
+            if pid[0] == "device":
+                return device
+            if pid[0] == "exec":
+                opts = compiler.get_compile_options(
+                    num_replicas=1, num_partitions=1,
+                    device_assignment=np.array([[device.id]]))
+                return self.backend.deserialize_executable(
+                    pid[1], executable_devices=self.execution_devices,
+                    compile_options=opts)
+            return super().persistent_load(pid)
+
+    serialized, in_tree, out_tree = payload
+    unloaded, args_info, no_kwargs = _OnDevice(
+        io.BytesIO(serialized), device.client, [device]).load()
+    return jax.stages.Compiled(unloaded.load(), [],
+                               in_tree.unflatten(args_info), out_tree,
+                               no_kwargs=no_kwargs)
+
+
+def _aot_remember(ck: Tuple[str, str], exe):
+    with _AOT_LOCK:
+        _AOT_EXES[ck] = exe
+        while len(_AOT_EXES) > _AOT_EXES_MAX:
+            _AOT_EXES.popitem(last=False)
+
+
+def _aot_cache_lookup(fingerprint: str, key: str,
+                      device) -> Optional[Callable]:
+    """Process-dict hit for this device first; then a payload of the same
+    portable program — the serialized on-disk file, or one serialized from
+    an executable another device of this process loaded — loaded onto
+    ``device``. A file that cannot be read back (torn write, foreign
+    pickle) is a miss — the caller compiles for real and republishes — but
+    a payload that fails to load raises: loading rests on JAX's own
+    serialization internals, and a break there must not hide as extra
+    compiles."""
     ck = (fingerprint, key)
+    portable = _portable(fingerprint)
     with _AOT_LOCK:
         exe = _AOT_EXES.get(ck)
         if exe is not None:
             _AOT_EXES.move_to_end(ck)
             return exe
+        sibling = next((e for (fp, k), e in _AOT_EXES.items()
+                        if k == key and _portable(fp) == portable), None)
     path = _aot_disk_file(fingerprint, key)
-    if path is None or not os.path.exists(path):
-        return None
-    try:
+    payload = None
+    if path is not None and os.path.exists(path):
+        try:
+            with open(path, "rb") as f:
+                payload = pickle.load(f)
+        except Exception:
+            payload = None
+    if payload is None and sibling is not None:
         from jax.experimental import serialize_executable as se
-        with open(path, "rb") as f:
-            payload = pickle.load(f)
-        exe = se.deserialize_and_load(*payload)
-    except Exception:
+        payload = se.serialize(sibling)
+    if payload is None:
         return None
-    with _AOT_LOCK:
-        _AOT_EXES[ck] = exe
-        while len(_AOT_EXES) > _AOT_EXES_MAX:
-            _AOT_EXES.popitem(last=False)
+    exe = _load_on(payload, device)
+    _aot_remember(ck, exe)
     return exe
 
 
@@ -209,11 +268,7 @@ def _aot_cache_publish(fingerprint: str, key: str, exe):
     """Record a freshly compiled executable: always into the process dict
     (in-process clones hit it), and — when a cache dir is configured —
     atomically onto disk so OTHER processes re-lower into a hit."""
-    ck = (fingerprint, key)
-    with _AOT_LOCK:
-        _AOT_EXES[ck] = exe
-        while len(_AOT_EXES) > _AOT_EXES_MAX:
-            _AOT_EXES.popitem(last=False)
+    _aot_remember((fingerprint, key), exe)
     path = _aot_disk_file(fingerprint, key)
     if path is None or os.path.exists(path):
         return
@@ -229,6 +284,16 @@ def _aot_cache_publish(fingerprint: str, key: str, exe):
         # disk publication is best-effort: a receiver that misses simply
         # pays one true compile (and is counted doing so)
         pass
+
+
+def current_device():
+    """The device JAX places new arrays on in this thread: the
+    ``jax.default_device`` in force (a live PCM worker runs its whole
+    thread under its own device), else the first device."""
+    dev = jax.config.jax_default_device
+    if dev is None or isinstance(dev, str):
+        return jax.devices(dev)[0]
+    return dev
 
 
 def _bucket(n: int, buckets: Sequence[int]) -> int:
@@ -249,7 +314,7 @@ class InferenceEngine:
     def __init__(self, model: Model, params, *, slots: int = 8,
                  cache_len: int = 512,
                  prefill_buckets: Sequence[int] = (32, 128, 512),
-                 cache_dtype=jnp.float32, rng_seed: int = 0,
+                 cache_dtype=None, rng_seed: int = 0,
                  extra: Optional[Dict] = None,
                  donate_cache: bool = True,
                  megastep: int = 1,
@@ -267,6 +332,14 @@ class InferenceEngine:
         self._donate_cache = bool(donate_cache)
         self.model = model
         self.cfg = model.cfg
+        # the device this engine's state and executables live on: where
+        # its weights are, else where this thread places new arrays
+        arrays = [leaf for leaf in jax.tree_util.tree_leaves(params)
+                  if isinstance(leaf, jax.Array)]
+        self.device = (next(iter(arrays[0].devices())) if arrays
+                       else current_device())
+        if cache_dtype is None:
+            cache_dtype = jnp.dtype(self.cfg.kv_cache_dtype)
         self.params = params
         self.slots = slots
         self.cache_len = cache_len
@@ -362,8 +435,8 @@ class InferenceEngine:
             elif (np.dtype(self._cache_dtype)
                   != np.dtype(jax.dtypes.canonicalize_dtype(cdt(self.cfg)))):
                 self.prefix_fallback = (
-                    "cache dtype narrows the compute dtype — shared prefix "
-                    "KV would round where a full prefill would not")
+                    "cache dtype differs from the compute dtype — shared "
+                    "prefix KV would round where a full prefill would not")
             elif 1024 % self.page_size:
                 self.prefix_fallback = (
                     f"page_size {self.page_size} does not divide the "
@@ -377,10 +450,10 @@ class InferenceEngine:
         # the live context, not allocated capacity. Only decoder-only
         # full-attention families qualify (ring buffers address the cache
         # modulo its physical size, so a sliced view changes semantics).
-        # use_kernels is excluded: the Pallas decode routing in
-        # attend_decode depends on the cache size it sees, so mixing
-        # prefix-view sizes across K could mix kernel/XLA numerics and
-        # break the cross-K greedy bit-parity guarantee. The paged path
+        # use_kernels is excluded: the Pallas decode kernel's block size
+        # depends on the cache size it sees, so mixing prefix-view sizes
+        # across K could change its summation order and break the cross-K
+        # greedy bit-parity guarantee. The paged path
         # subsumes the prefix view entirely (page-count buckets).
         prefixable = (not self._paged
                       and getattr(self.cfg, "family", "") in ("dense", "moe")
@@ -457,6 +530,10 @@ class InferenceEngine:
             self._prefill_jit = jax.jit(self._prefill_impl,
                                         donate_argnums=pre_donate)
         self._exe: Dict[Tuple, Callable] = {}         # AOT executables
+        if params is not None:
+            for name in self._DEVICE_STATE_FIELDS:
+                setattr(self, name, jax.device_put(getattr(self, name),
+                                                   self.device))
 
     # ------------------------------------------------------------- jitted --
     def _prefill_impl(self, params, tokens, lens, slot_ids, valid,
@@ -770,13 +847,14 @@ class InferenceEngine:
             return exe
         fp = self.aot_fingerprint
         if self._aot_shared:
-            exe = _aot_cache_lookup(fp, repr(key))
+            exe = _aot_cache_lookup(fp, repr(key), self.device)
             if exe is not None:
                 self.stats.aot_cache_hits += 1
                 self._exe[key] = exe
                 return exe
         t0 = time.monotonic()
-        exe = jitfn.lower(*args).compile()
+        with jax.default_device(self.device):
+            exe = jitfn.lower(*args).compile()
         self.compile_seconds += time.monotonic() - t0
         self.stats.compiles += 1
         self._exe[key] = exe
@@ -976,17 +1054,29 @@ class InferenceEngine:
         return host
 
     def restore_device_state(self, host_state: Dict):
-        """Promote: push a previously offloaded state dict back onto the
-        device in one ``jax.device_put``. Executables cached in ``_exe``
-        are reused as-is, so a restored engine decodes bit-identically to
-        one that never left the device — at transfer cost, not
-        build+compile cost."""
+        """Promote: push a previously offloaded state dict onto the device
+        this thread places new arrays on (a live worker's own device) in
+        one ``jax.device_put``. Executables cached in
+        ``_exe`` are reused as-is, so a restored engine decodes
+        bit-identically to one that never left the device — at transfer
+        cost, not build+compile cost. An engine restored onto another
+        device resolves its executables again through the AOTRecipe cache
+        (loaded for the new device, not compiled)."""
         if not self.offloaded:
             raise RuntimeError("engine device state is already resident")
         missing = [n for n in self._DEVICE_STATE_FIELDS
                    if n not in host_state]
         if missing:
             raise ValueError(f"snapshot is missing engine state: {missing}")
+        device = current_device()
+        if device != self.device:
+            self._exe = {}
+            self._aot_shared = True
+            self.device = device
+        with jax.default_device(device):
+            self._restore_on(host_state, device)
+
+    def _restore_on(self, host_state: Dict, device):
         if self._paged:
             if "_paged_live_ids" not in host_state:
                 raise ValueError("paged snapshot is missing the live-page "
@@ -997,9 +1087,9 @@ class InferenceEngine:
                 raise ValueError(
                     f"paged snapshot refcount vector ({len(refs)}) does not "
                     f"match its live-page index ({live.size})")
-            device = jax.device_put({n: host_state[n]
-                                     for n in self._DEVICE_STATE_FIELDS
-                                     if n != "cache"})
+            state = jax.device_put({n: host_state[n]
+                                    for n in self._DEVICE_STATE_FIELDS
+                                    if n != "cache"}, device)
             # rebuild the pool around the snapshotted live pages; released
             # pages and TRASH come back zeroed, which is invisible to every
             # read (non-owned columns are length-masked to exact-zero
@@ -1009,13 +1099,14 @@ class InferenceEngine:
             if live.size:
                 pool = paging.scatter_live(
                     pool, jnp.asarray(live),
-                    jax.device_put(host_state["cache"]), self._axes)
-            device["cache"] = pool
+                    jax.device_put(host_state["cache"], device), self._axes)
+            state["cache"] = jax.device_put(pool, device)
         else:
-            device = jax.device_put(
-                {n: host_state[n] for n in self._DEVICE_STATE_FIELDS})
+            state = jax.device_put(
+                {n: host_state[n] for n in self._DEVICE_STATE_FIELDS},
+                device)
         for name in self._DEVICE_STATE_FIELDS:
-            setattr(self, name, device[name])
+            setattr(self, name, state[name])
 
     def _require_resident(self):
         if self.offloaded:
@@ -1111,12 +1202,13 @@ class InferenceEngine:
     @property
     def aot_fingerprint(self) -> str:
         """The AOTRecipe cache namespace for this engine's executables:
-        a digest of everything that shapes a lowering — model config,
-        slot/cache geometry, bucket sets, megastep K, paged/prefix
-        resolution, donation — plus the jax/jaxlib versions and XLA
-        backend platform. Two engines with equal fingerprints lower
-        byte-compatible executables, so one's compile is the other's
-        cache hit (in-process or across processes)."""
+        ``<portable>@<device id>``. The portable digest covers everything
+        that shapes a lowering — model config, slot/cache geometry, bucket
+        sets, megastep K, paged/prefix resolution, donation — plus the
+        jax/jaxlib versions, the backend platform and the device kind. Two
+        engines with equal portable digests lower byte-compatible
+        executables, so one's compile is the other's cache hit (in-process
+        or across processes, loaded onto the hitting engine's device)."""
         fp = self.__dict__.get("_aot_fp")
         if fp is None:
             import jaxlib
@@ -1136,12 +1228,13 @@ class InferenceEngine:
                 "extra": None if self.extra is None else hashlib.sha256(
                     pickle.dumps(self.extra)).hexdigest(),
                 "jax": jax.__version__, "jaxlib": jaxlib.__version__,
-                "backend": jax.default_backend(),
+                "backend": self.device.platform,
+                "device_kind": self.device.device_kind,
             }
             fp = hashlib.sha256(
                 json.dumps(spec, sort_keys=True).encode()).hexdigest()[:16]
             self.__dict__["_aot_fp"] = fp
-        return fp
+        return f"{fp}@{self.device.id}"
 
     def wire_recipe(self) -> Dict:
         """The engine's wire-format identity: a JSON-serializable
@@ -1170,7 +1263,7 @@ class InferenceEngine:
             "prefix_sharing": self._prefix_cache is not None,
             "fingerprint": self.aot_fingerprint,
             "jax": jax.__version__, "jaxlib": jaxlib.__version__,
-            "backend": jax.default_backend(),
+            "backend": self.device.platform,
         }
         if self.extra is not None:
             rec["extra_b64"] = base64.b64encode(

@@ -78,6 +78,20 @@ def test_attend_decode_kernel_routing():
     assert err < 2e-4, err
 
 
+@pytest.mark.parametrize("Skv,want", [(600, 120), (5, 5), (131, None),
+                                      (2 * 131, None)])
+def test_flash_decode_blocks_divide_the_cache(Skv, want):
+    """The contiguous-cache block is the largest divisor of the cache
+    length that fits; a length with no divisor of at least 8 tokens is
+    refused rather than run a grid step per token or two."""
+    from repro.kernels.decode_attention import _block_tokens
+    if want is None:
+        with pytest.raises(ValueError, match="multiple of 8"):
+            _block_tokens(Skv, 32, 512)
+    else:
+        assert _block_tokens(Skv, 32, 512) == want
+
+
 def test_flash_decode_active_mask():
     """The megastep's per-slot mask: inactive slots' lengths are forced to
     0 so every KV block is skipped; active slots match the oracle."""

@@ -279,6 +279,24 @@ class TestTransportKindCalibration:
 # ---------------------------------------------------------------------------
 # whole-node subprocesses over loopback
 # ---------------------------------------------------------------------------
+def test_node_process_needs_a_cpu_environment():
+    """A node child must never reach for a chip its parent (or a sibling)
+    holds: without JAX_PLATFORMS=cpu the spawn is refused."""
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        spawn_node_process(("127.0.0.1", 1), "w", env=env)
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        spawn_node_process(("127.0.0.1", 1), "w",
+                           env=dict(env, JAX_PLATFORMS="tpu"))
+
+
+def test_node_rejects_an_unknown_profile():
+    from repro.cluster.node import run
+    with pytest.raises(ValueError, match="unknown --profile"):
+        run(["--connect", "127.0.0.1:1", "--worker-id", "w",
+             "--profile", "no-such-gpu"])
+
+
 class TestNodeProcesses:
     @staticmethod
     def _spawn(addr, wid, **kw):

@@ -13,6 +13,7 @@ from repro.core import (ContextAwareScheduler, ContextMode, ContextRecipe,
                         SimulatorBackend, SnapshotPool, Task, Tier,
                         TierFullError, load_context, make_recipe)
 from repro.core.context import GB
+from repro.core.store import DEFAULT_DEVICE_BYTES, device_tier_bytes
 
 
 # ---------------------------------------------------------- store admit ----
@@ -57,6 +58,56 @@ class TestAdmitRefusal:
         s = ContextStore(device_bytes=1 * GB)
         with pytest.raises(TierFullError):
             s.admit("big", Tier.DEVICE, 2 * GB)
+
+
+class _FakeDevice:
+    def __init__(self, platform, stats):
+        self.platform, self.device_kind, self._stats = platform, "fake", stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("platform,stats,want", [
+    ("cpu", None, DEFAULT_DEVICE_BYTES),
+    ("tpu", {"bytes_limit": 15 * GB}, 15 * GB),
+    ("tpu", None, RuntimeError),
+    ("tpu", {"bytes_in_use": 0}, RuntimeError),
+])
+def test_device_tier_sized_from_the_device(platform, stats, want):
+    """A live worker's DEVICE tier holds what its device holds; only the
+    CPU keeps the default, and an unreadable accelerator is refused."""
+    dev = _FakeDevice(platform, stats)
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError):
+            device_tier_bytes(dev)
+    else:
+        assert device_tier_bytes(dev) == want
+
+
+def test_a_chip_holds_one_live_worker(monkeypatch):
+    """Live workers take one accelerator each, and each DEVICE tier is its
+    whole chip; a worker beyond the chips is refused instead of admitting
+    a chip's memory twice."""
+    import types
+    from repro.core import manager as manager_mod
+    chips = [_FakeDevice("tpu", {"bytes_limit": 16 * GB}) for _ in range(2)]
+    for i, chip in enumerate(chips):
+        chip.id = i
+    mgr = PCMManager(n_workers=0)
+    monkeypatch.setattr(manager_mod, "jax",
+                        types.SimpleNamespace(local_devices=lambda: chips))
+    try:
+        for chip in chips:
+            assert mgr._free_device() is chip
+            w = manager_mod.LiveWorker(f"w{chip.id}", mgr, device=chip)
+            assert w.store.capacity[Tier.DEVICE] == 16 * GB
+            mgr.workers[w.worker_id] = w
+        with pytest.raises(RuntimeError, match="a chip holds one"):
+            mgr._free_device()
+    finally:
+        mgr.workers.clear()
+        mgr.shutdown()
 
 
 # ----------------------------------------------------------- one clock -----

@@ -1,0 +1,93 @@
+"""The main-path Pallas kernels compile for a TPU v5e at real widths.
+
+No chip is needed: the TPU compiler compiles against a described ``v5e:2x2``
+topology, which refuses what interpret mode accepts (block shapes that do
+not tile, kernels that exceed the scoped VMEM). Every compile must keep the
+kernel, so each asserts a ``tpu_custom_call`` in the compiled module.
+
+The topology is described inside a fixture: only one process at a time may
+load the TPU library, so nothing here touches it at import or collection.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.decode_attention import (flash_decode, paged_flash_decode,
+                                            paged_mla_decode)
+from repro.kernels.flash_attention import flash_attention_bhsd
+
+SLOTS, PAGE, NUM_PAGES, MAX_PAGES = 16, 64, 256, 8
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these compiles
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _gqa_widths():
+    cfg = get_config("smollm2-1.7b")
+    return (cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim,
+            jnp.dtype(cfg.compute_dtype))
+
+
+def test_paged_flash_decode_compiles(one_chip):
+    H, Hkv, D, dt = _gqa_widths()
+    pool = (NUM_PAGES + 1, PAGE, Hkv, D)
+    _compile(lambda q, k, v, pt, n: paged_flash_decode(q, k, v, pt, n,
+                                                       scale=D ** -0.5),
+             one_chip, ((SLOTS, H, D), dt), (pool, dt), (pool, dt),
+             ((SLOTS, MAX_PAGES), jnp.int32), ((SLOTS,), jnp.int32))
+
+
+@pytest.mark.parametrize("cache_len", [512, 1024, 600])
+def test_flash_decode_compiles(one_chip, cache_len):
+    H, Hkv, D, dt = _gqa_widths()
+    cache = (SLOTS, cache_len, Hkv, D)
+    _compile(lambda q, k, v, n: flash_decode(q, k, v, n, scale=D ** -0.5),
+             one_chip, ((SLOTS, H, D), dt), (cache, dt), (cache, dt),
+             ((SLOTS,), jnp.int32))
+
+
+def test_flash_attention_prefill_compiles(one_chip):
+    H, _, D, dt = _gqa_widths()
+    x = (4 * H, 512, D)                 # (batch * heads, bucket, head_dim)
+    _compile(lambda q, k, v: flash_attention_bhsd(q, k, v, causal=True,
+                                                  scale=D ** -0.5),
+             one_chip, (x, dt), (x, dt), (x, dt))
+
+
+def test_paged_mla_decode_compiles(one_chip):
+    cfg = get_config("deepseek-v2-lite-16b")
+    H, R, Dr = cfg.n_heads, cfg.mla.kv_lora_rank, cfg.mla.qk_rope_head_dim
+    dt = jnp.dtype(cfg.compute_dtype)
+    _compile(lambda ql, qr, c, kr, pt, n: paged_mla_decode(
+                 ql, qr, c, kr, pt, n, scale=(R + Dr) ** -0.5),
+             one_chip, ((SLOTS, H, R), dt), ((SLOTS, H, Dr), dt),
+             ((NUM_PAGES + 1, PAGE, R), dt), ((NUM_PAGES + 1, PAGE, Dr), dt),
+             ((SLOTS, MAX_PAGES), jnp.int32), ((SLOTS,), jnp.int32))
