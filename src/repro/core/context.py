@@ -550,21 +550,26 @@ def restore_context(snap: ContextSnapshot, worker_id: str = "local",
     ``device_put`` of the current one — see :func:`_streamed_unspill`).
     No builder call, no XLA compile: the executables never left the
     component objects. ``restore_seconds`` on the returned Context records
-    the real promotion cost; ``stage_seconds`` carries the per-stage
-    (disk/h2d) split for pipeline calibration when streamed."""
+    the real promotion cost, up to the restored device state being ready;
+    ``stage_seconds`` carries the per-stage (disk/h2d) split for pipeline
+    calibration when streamed."""
+    import jax
     t0 = time.monotonic()
     stage_seconds: Dict[str, list] = {}
-    if snap.spilled:
-        if spill_store is None:
-            raise ValueError(
-                f"snapshot {snap.key} is spilled to disk; a spill store is "
-                "required to restore it")
-        if streamed:
-            _streamed_unspill(snap, spill_store, stage_seconds)
-        else:
-            snap.unspill(spill_store)
-    for i, comp in enumerate(_offloadable(snap.value)):
-        comp.restore_device_state(snap.host_state[f"c{i}"])
+    with jax.profiler.TraceAnnotation("pcm.restore", key=snap.key,
+                                      spilled=snap.spilled):
+        if snap.spilled:
+            if spill_store is None:
+                raise ValueError(
+                    f"snapshot {snap.key} is spilled to disk; a spill store "
+                    "is required to restore it")
+            if streamed:
+                _streamed_unspill(snap, spill_store, stage_seconds)
+            else:
+                snap.unspill(spill_store)
+        restored = [comp.restore_device_state(snap.host_state[f"c{i}"])
+                    for i, comp in enumerate(_offloadable(snap.value))]
+        jax.block_until_ready(restored)
     snap.host_state = {}
     ctx = Context(recipe=snap.recipe, value=snap.value, worker_id=worker_id,
                   build_seconds=snap.build_seconds,

@@ -33,6 +33,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Mapping, Optional, Set
 
+import jax
+
 from repro.core.context import (Context, ContextRecipe, materialize,
                                 restore_context, snapshot_context)
 from repro.core.transfer import FetchSource
@@ -165,7 +167,8 @@ class Library:
                     self._record_source(
                         FetchSource.DISK if from_disk else FetchSource.POOL)
             if ctx is None:
-                ctx = materialize(recipe, self.worker_id)
+                with jax.profiler.TraceAnnotation("pcm.build", key=key):
+                    ctx = materialize(recipe, self.worker_id)
                 self.builder_calls += 1
                 self.build_seconds_total += ctx.build_seconds
                 self.aot_seconds_total += ctx.aot_seconds
@@ -267,14 +270,16 @@ class Library:
         token = None
         if named:
             installed: Dict[str, Context] = {}
-            for cname, rec in named.items():
-                cold = cold or not self.has(rec.key())
-                ctx = self.ensure(rec)
-                ctx.touch()
-                installed[cname] = ctx
+            with jax.profiler.TraceAnnotation("pcm.context"):
+                for cname, rec in named.items():
+                    cold = cold or not self.has(rec.key())
+                    ctx = self.ensure(rec)
+                    ctx.touch()
+                    installed[cname] = ctx
             token = _current.set(installed)
         try:
-            return fn(*args, **kwargs)
+            with jax.profiler.TraceAnnotation("pcm.fn"):
+                return fn(*args, **kwargs)
         finally:
             if token is not None:
                 _current.reset(token)

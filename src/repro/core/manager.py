@@ -280,7 +280,9 @@ class LiveWorker:
                 break
             try:
                 if kind == "start":
-                    self._handle_start(msg[1])
+                    with jax.profiler.TraceAnnotation("pcm.task",
+                                                      task_id=msg[1]):
+                        self._handle_start(msg[1])
                 elif kind == "fetch":
                     self._handle_fetch(msg[1], msg[2])
                 elif kind == "donate":
@@ -1774,13 +1776,15 @@ class PCMManager:
             named = {recipe.name: recipe}
         with self._cond:
             task_id = f"t{next(self._task_ids):05d}"
-            task = Task(task_id=task_id, recipes=tuple(named.values()),
-                        context_names=tuple(named.keys()), n_items=n_items,
-                        priority=priority, payload=(fn, args, kwargs or {}))
-            fut = Future(task_id, self)
-            self._futures[task_id] = fut
-            acts = self.scheduler.submit(task, self.now)
-            self._dispatch(acts)
+            with jax.profiler.TraceAnnotation("pcm.submit", task_id=task_id):
+                task = Task(task_id=task_id, recipes=tuple(named.values()),
+                            context_names=tuple(named.keys()),
+                            n_items=n_items, priority=priority,
+                            payload=(fn, args, kwargs or {}))
+                fut = Future(task_id, self)
+                self._futures[task_id] = fut
+                acts = self.scheduler.submit(task, self.now)
+                self._dispatch(acts)
             return fut
 
     # ----------------------------------------------------------- contexts --

@@ -213,6 +213,7 @@ def blockwise_attention(q, k, v, *, scale: float, causal: bool,
 
 
 # -------------------------------------------------------------- prefill ----
+@jax.named_scope("attention")
 def attend_prefill(p, x, cfg, *, positions, layer_window: int = 0,
                    memory=None, causal: bool = True,
                    kv_len: Optional[jax.Array] = None,
@@ -240,6 +241,7 @@ def attend_prefill(p, x, cfg, *, positions, layer_window: int = 0,
     return (y, (k, v)) if return_kv else (y, None)
 
 
+@jax.named_scope("merge_rows")
 def _merge_rows(view: jax.Array, tail: jax.Array,
                 starts: jax.Array) -> jax.Array:
     """Overlay freshly computed tail rows onto a gathered cache view.
@@ -263,6 +265,7 @@ def _merge_rows(view: jax.Array, tail: jax.Array,
                      gathered, view)
 
 
+@jax.named_scope("attention")
 def attend_prefill_shared(p, x, cfg, *, positions, starts, kv_len,
                           view_k, view_v):
     """Tail-only prefill attention for page-level prefix sharing.
@@ -297,6 +300,7 @@ def attend_prefill_shared(p, x, cfg, *, positions, starts, kv_len,
 
 
 # --------------------------------------------------------------- decode ----
+@jax.named_scope("attention")
 def attend_decode(p, x, cfg, *, cache_k, cache_v, lengths,
                   layer_window: int = 0, memory_kv=None):
     """One-token decode. x (B,1,d); cache_k/v (B,Scache,Hkv,D); lengths (B,).
@@ -385,6 +389,7 @@ def _paged_gather(pages: jax.Array, page_table: jax.Array) -> jax.Array:
                                                  pages.shape[2:])
 
 
+@jax.named_scope("attention")
 def paged_attend_decode(p, x, cfg, *, k_pages, v_pages, page_table, lengths,
                         active):
     """One-token GQA decode against a paged KV cache.
@@ -432,6 +437,7 @@ def paged_attend_decode(p, x, cfg, *, k_pages, v_pages, page_table, lengths,
     return y, k_pages, v_pages
 
 
+@jax.named_scope("attention")
 def paged_mla_decode(p, x, cfg, *, ckv_pages, krope_pages, page_table,
                      lengths, active):
     """Absorbed-matrix MLA decode against paged compressed latents.
@@ -565,6 +571,7 @@ def _mla_latent(p, x, cfg):
     return ckv, k_rope
 
 
+@jax.named_scope("attention")
 def mla_prefill(p, x, cfg, *, positions, kv_len=None, return_kv: bool = False,
                 chunk: int = 1024):
     """Blockwise MLA prefill with per-chunk KV decompression (FlashMLA-style)."""
@@ -625,6 +632,7 @@ def mla_prefill(p, x, cfg, *, positions, kv_len=None, return_kv: bool = False,
     return (y, (ckv, k_rope)) if return_kv else (y, None)
 
 
+@jax.named_scope("attention")
 def mla_decode(p, x, cfg, *, cache_ckv, cache_krope, lengths):
     """Absorbed-matrix MLA decode: attention runs in the latent space.
 

@@ -93,6 +93,7 @@ def embed(p: dict, tokens: jax.Array, cfg) -> jax.Array:
     return shard(x, "batch", "seq", None)
 
 
+@jax.named_scope("logits")
 def unembed(p: dict, x: jax.Array, cfg) -> jax.Array:
     w = p["tok"].T if "unembed" not in p else p["unembed"]
     logits = jnp.einsum("...d,dv->...v", x.astype(cdt(cfg)), w.astype(cdt(cfg)))
@@ -132,6 +133,7 @@ def init_mlp(key, cfg, d_ff: Optional[int] = None, activation: Optional[str] = N
     return p
 
 
+@jax.named_scope("mlp")
 def apply_mlp(p: dict, x: jax.Array, cfg, activation: Optional[str] = None,
               sharded: bool = True) -> jax.Array:
     act = activation or cfg.activation

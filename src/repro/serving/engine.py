@@ -853,7 +853,8 @@ class InferenceEngine:
                 self._exe[key] = exe
                 return exe
         t0 = time.monotonic()
-        with jax.default_device(self.device):
+        with jax.profiler.TraceAnnotation("engine.compile", key=repr(key)), \
+                jax.default_device(self.device):
             exe = jitfn.lower(*args).compile()
         self.compile_seconds += time.monotonic() - t0
         self.stats.compiles += 1
@@ -874,9 +875,10 @@ class InferenceEngine:
         jkey = (prefix, restore)
         jit = self._megastep_jits.get(jkey)
         if jit is None:
-            jit = jax.jit(functools.partial(self._megastep_impl,
-                                            prefix=prefix, restore=restore),
-                          donate_argnums=self._mega_donate)
+            impl = functools.update_wrapper(
+                functools.partial(self._megastep_impl, prefix=prefix,
+                                  restore=restore), self._megastep_impl)
+            jit = jax.jit(impl, donate_argnums=self._mega_donate)
             self._megastep_jits[jkey] = jit
         return jit
 
@@ -900,9 +902,10 @@ class InferenceEngine:
         jkey = ("paged", npages)
         jit = self._megastep_jits.get(jkey)
         if jit is None:
-            jit = jax.jit(functools.partial(self._paged_megastep_impl,
-                                            npages=npages),
-                          donate_argnums=self._mega_donate)
+            impl = functools.update_wrapper(
+                functools.partial(self._paged_megastep_impl, npages=npages),
+                self._paged_megastep_impl)
+            jit = jax.jit(impl, donate_argnums=self._mega_donate)
             self._megastep_jits[jkey] = jit
         st = self._state_sds()
         params = jax.tree_util.tree_map(self._sds, self.params)
@@ -1061,7 +1064,8 @@ class InferenceEngine:
         bit-identically to one that never left the device — at transfer
         cost, not build+compile cost. An engine restored onto another
         device resolves its executables again through the AOTRecipe cache
-        (loaded for the new device, not compiled)."""
+        (loaded for the new device, not compiled). Returns the restored
+        device state, which may still be in flight."""
         if not self.offloaded:
             raise RuntimeError("engine device state is already resident")
         missing = [n for n in self._DEVICE_STATE_FIELDS
@@ -1074,7 +1078,7 @@ class InferenceEngine:
             self._aot_shared = True
             self.device = device
         with jax.default_device(device):
-            self._restore_on(host_state, device)
+            return self._restore_on(host_state, device)
 
     def _restore_on(self, host_state: Dict, device):
         if self._paged:
@@ -1107,6 +1111,7 @@ class InferenceEngine:
                 device)
         for name in self._DEVICE_STATE_FIELDS:
             setattr(self, name, state[name])
+        return state
 
     def _require_resident(self):
         if self.offloaded:
@@ -1337,11 +1342,15 @@ class InferenceEngine:
         for the whole active set to finish."""
         self._require_resident()
         finished: List[Request] = []
-        if self.queue and self.free_slots and (
-                self.admission == "continuous" or not self.active):
-            finished.extend(self._admit_wave())
-        if self.active:
-            finished.extend(self._megastep_wave())
+        with jax.profiler.TraceAnnotation("engine.step",
+                                          step=self.stats.steps):
+            if self.queue and self.free_slots and (
+                    self.admission == "continuous" or not self.active):
+                with jax.profiler.TraceAnnotation("engine.admit"):
+                    finished.extend(self._admit_wave())
+            if self.active:
+                with jax.profiler.TraceAnnotation("engine.decode"):
+                    finished.extend(self._megastep_wave())
         self.stats.steps += 1
         return finished
 
@@ -1534,46 +1543,55 @@ class InferenceEngine:
                         if wave_pins[i] >= 0:
                             pt_src[i, start_pages[i]] = wave_pins[i]
                     exe = self._shared_prefill_exe(bucket)
-                    (first, row_active, self.page_table, self.cache,
-                     self.lengths, self.last_tokens, self.temps,
-                     self.active_mask, self.gen_counts, self.max_news,
-                     self.stop_table, self._rng) = exe(
-                        self.params, jnp.asarray(toks), jnp.asarray(lens),
-                        jnp.asarray(starts_np), jnp.asarray(slot_ids),
-                        jnp.asarray(valid), jnp.asarray(temps),
-                        jnp.asarray(max_new), jnp.asarray(stops),
-                        jnp.asarray(start_pages), jnp.asarray(pt_src),
-                        jnp.asarray(pt_dst), self.page_table, self.cache,
-                        self.lengths, self.last_tokens, self.temps,
-                        self.active_mask, self.gen_counts, self.max_news,
-                        self.stop_table, self._rng)
+                    with jax.profiler.TraceAnnotation(
+                            "engine.prefill", bucket=bucket, n=n,
+                            shared=shared_wave):
+                        (first, row_active, self.page_table, self.cache,
+                         self.lengths, self.last_tokens, self.temps,
+                         self.active_mask, self.gen_counts, self.max_news,
+                         self.stop_table, self._rng) = exe(
+                            self.params, jnp.asarray(toks), jnp.asarray(lens),
+                            jnp.asarray(starts_np), jnp.asarray(slot_ids),
+                            jnp.asarray(valid), jnp.asarray(temps),
+                            jnp.asarray(max_new), jnp.asarray(stops),
+                            jnp.asarray(start_pages), jnp.asarray(pt_src),
+                            jnp.asarray(pt_dst), self.page_table, self.cache,
+                            self.lengths, self.last_tokens, self.temps,
+                            self.active_mask, self.gen_counts, self.max_news,
+                            self.stop_table, self._rng)
                 else:
                     exe = self._prefill_exe(bucket)
-                    (first, row_active, self.page_table, self.cache,
-                     self.lengths, self.last_tokens, self.temps,
-                     self.active_mask, self.gen_counts, self.max_news,
-                     self.stop_table, self._rng) = exe(
+                    with jax.profiler.TraceAnnotation(
+                            "engine.prefill", bucket=bucket, n=n,
+                            shared=shared_wave):
+                        (first, row_active, self.page_table, self.cache,
+                         self.lengths, self.last_tokens, self.temps,
+                         self.active_mask, self.gen_counts, self.max_news,
+                         self.stop_table, self._rng) = exe(
+                            self.params, jnp.asarray(toks), jnp.asarray(lens),
+                            jnp.asarray(slot_ids), jnp.asarray(valid),
+                            jnp.asarray(temps), jnp.asarray(max_new),
+                            jnp.asarray(stops), jnp.asarray(pt_dst),
+                            self.page_table, self.cache, self.lengths,
+                            self.last_tokens, self.temps, self.active_mask,
+                            self.gen_counts, self.max_news, self.stop_table,
+                            self._rng)
+            else:
+                exe = self._prefill_exe(bucket)
+                with jax.profiler.TraceAnnotation(
+                        "engine.prefill", bucket=bucket, n=n,
+                        shared=shared_wave):
+                    (first, row_active, self.cache, self.lengths,
+                     self.last_tokens, self.temps, self.active_mask,
+                     self.gen_counts, self.max_news, self.stop_table,
+                     self._rng) = exe(
                         self.params, jnp.asarray(toks), jnp.asarray(lens),
                         jnp.asarray(slot_ids), jnp.asarray(valid),
                         jnp.asarray(temps), jnp.asarray(max_new),
-                        jnp.asarray(stops), jnp.asarray(pt_dst),
-                        self.page_table, self.cache, self.lengths,
+                        jnp.asarray(stops), self.cache, self.lengths,
                         self.last_tokens, self.temps, self.active_mask,
                         self.gen_counts, self.max_news, self.stop_table,
                         self._rng)
-            else:
-                exe = self._prefill_exe(bucket)
-                (first, row_active, self.cache, self.lengths,
-                 self.last_tokens, self.temps, self.active_mask,
-                 self.gen_counts, self.max_news, self.stop_table,
-                 self._rng) = exe(
-                    self.params, jnp.asarray(toks), jnp.asarray(lens),
-                    jnp.asarray(slot_ids), jnp.asarray(valid),
-                    jnp.asarray(temps), jnp.asarray(max_new),
-                    jnp.asarray(stops), self.cache, self.lengths,
-                    self.last_tokens, self.temps, self.active_mask,
-                    self.gen_counts, self.max_news, self.stop_table,
-                    self._rng)
         except BaseException:
             # reservation-leak fix: an admission that fails to dispatch
             # must hand back everything it claimed — pages (including
@@ -1610,7 +1628,8 @@ class InferenceEngine:
             self.stats.cow_copies += sum(1 for p in wave_pins if p >= 0)
 
         # one host sync per wave: the first token + immediately-done flags
-        first_np, row_active_np = jax.device_get((first, row_active))
+        with jax.profiler.TraceAnnotation("engine.sync", of="prefill"):
+            first_np, row_active_np = jax.device_get((first, row_active))
         now = time.monotonic()
         done: List[Request] = []
         for i, r in enumerate(wave):
@@ -1667,20 +1686,21 @@ class InferenceEngine:
         if not entries:
             return
         exe = self._cow_exe()
-        for i in range(0, len(entries), self.slots):
-            chunk = entries[i:i + self.slots]
-            # pads replicate the chunk's first entry: duplicate scatter
-            # indices carry identical values, so the write stays
-            # deterministic and the repeated page copy is a no-op
-            chunk = chunk + [chunk[0]] * (self.slots - len(chunk))
-            rows = np.array([e[0] for e in chunk], np.int32)
-            cols = np.array([e[1] for e in chunk], np.int32)
-            src = np.array([e[2] for e in chunk], np.int32)
-            dst = np.array([e[3] for e in chunk], np.int32)
-            self.page_table, self.cache = exe(
-                self.page_table, self.cache, jnp.asarray(src),
-                jnp.asarray(dst), jnp.asarray(rows), jnp.asarray(cols),
-                jnp.ones((self.slots,), bool))
+        with jax.profiler.TraceAnnotation("engine.cow", copies=len(entries)):
+            for i in range(0, len(entries), self.slots):
+                chunk = entries[i:i + self.slots]
+                # pads replicate the chunk's first entry: duplicate scatter
+                # indices carry identical values, so the write stays
+                # deterministic and the repeated page copy is a no-op
+                chunk = chunk + [chunk[0]] * (self.slots - len(chunk))
+                rows = np.array([e[0] for e in chunk], np.int32)
+                cols = np.array([e[1] for e in chunk], np.int32)
+                src = np.array([e[2] for e in chunk], np.int32)
+                dst = np.array([e[3] for e in chunk], np.int32)
+                self.page_table, self.cache = exe(
+                    self.page_table, self.cache, jnp.asarray(src),
+                    jnp.asarray(dst), jnp.asarray(rows), jnp.asarray(cols),
+                    jnp.ones((self.slots,), bool))
         self.stats.cow_copies += len(entries)
 
     def _megastep_wave(self) -> List[Request]:
@@ -1693,27 +1713,35 @@ class InferenceEngine:
                                 and self.admission == "continuous")
         if self._paged:
             self.stats.live_pages = self._alloc.live_pages
-            exe = self._paged_megastep_exe(self._decode_npages())
-            (self.cache, self.lengths, self.last_tokens, self.active_mask,
-             self.gen_counts, self._rng, block, produced) = exe(
-                self.params, self.page_table, self.cache, self.lengths,
-                self.last_tokens, self.temps, self.active_mask,
-                self.gen_counts, self.max_news, self.stop_table, self._rng,
-                has_queue)
+            npages = self._decode_npages()
+            exe = self._paged_megastep_exe(npages)
+            with jax.profiler.TraceAnnotation("engine.megastep",
+                                              npages=npages):
+                (self.cache, self.lengths, self.last_tokens,
+                 self.active_mask, self.gen_counts, self._rng, block,
+                 produced) = exe(
+                    self.params, self.page_table, self.cache, self.lengths,
+                    self.last_tokens, self.temps, self.active_mask,
+                    self.gen_counts, self.max_news, self.stop_table,
+                    self._rng, has_queue)
         else:
             # the restore pass is only needed when free slots exist whose
             # cache rows must survive the megastep untouched
-            exe = self._megastep_exe(self._decode_prefix(),
-                                     len(self.active) < self.slots)
-            (self.cache, self.lengths, self.last_tokens, self.active_mask,
-             self.gen_counts, self._rng, block, produced) = exe(
-                self.params, self.cache, self.lengths, self.last_tokens,
-                self.temps, self.active_mask, self.gen_counts, self.max_news,
-                self.stop_table, self._rng, has_queue)
+            prefix = self._decode_prefix()
+            exe = self._megastep_exe(prefix, len(self.active) < self.slots)
+            with jax.profiler.TraceAnnotation("engine.megastep",
+                                              prefix=prefix):
+                (self.cache, self.lengths, self.last_tokens,
+                 self.active_mask, self.gen_counts, self._rng, block,
+                 produced) = exe(
+                    self.params, self.cache, self.lengths, self.last_tokens,
+                    self.temps, self.active_mask, self.gen_counts,
+                    self.max_news, self.stop_table, self._rng, has_queue)
 
         # the single host sync for up to K tokens across all slots
-        block_np, produced_np, active_np = jax.device_get(
-            (block, produced, self.active_mask))
+        with jax.profiler.TraceAnnotation("engine.sync", of="megastep"):
+            block_np, produced_np, active_np = jax.device_get(
+                (block, produced, self.active_mask))
         now = time.monotonic()
         done: List[Request] = []
         for s, r in list(self.active.items()):

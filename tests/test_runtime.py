@@ -317,6 +317,28 @@ class TestSnapshotPool:
         np.testing.assert_array_equal(eng.weights,
                                       np.arange(1000, dtype=np.float64))
 
+    def test_restore_seconds_waits_for_the_restored_state(self):
+        """``restore_seconds`` ends at a barrier on the state the
+        components hand back, not when the copies were only issued."""
+        class Pending:                          # a copy still in flight
+            def block_until_ready(self):
+                time.sleep(0.05)
+                return self
+
+        class SlowEngine(FakeEngine):
+            def restore_device_state(self, host_state):
+                super().restore_device_state(host_state)
+                return {"weights": Pending()}
+
+        pool = SnapshotPool()
+        lib = Library("w0", snapshots=pool)
+        rec = make_recipe("slow", SlowEngine, host_bytes=0)
+        lib.ensure(rec)
+        lib.demote(rec.key())
+        ctx = lib.ensure(rec)
+        assert ctx.restored and ctx.restore_seconds >= 0.05
+        assert lib.restore_seconds_total == ctx.restore_seconds
+
     def test_demote_without_pool_refuses_not_destroys(self):
         lib = Library("w0")                       # no snapshot pool
         builds = []
@@ -537,6 +559,23 @@ class TestEngineTierRoundTrip:
         assert eng2.stats.compiles == compiles_before   # ZERO compiles
         assert lib.restores == 1 and ctx2.restored
         assert ctx2.restore_seconds > 0
+
+    def test_restore_seconds_covers_the_device_copy(self, smol):
+        """The restored engine's cache is ready when ``restore_seconds``
+        is taken: a later barrier on it waits for nothing the restore
+        left out."""
+        import jax
+        cfg, model, params = smol
+        pool = SnapshotPool()
+        lib = Library("w0", snapshots=pool)
+        rec = _paged_engine_recipe("barrier", model, params)
+        eng = lib.ensure(rec).value["engine"]
+        eng.generate(_prompts(cfg, 2), max_new_tokens=3)
+        lib.demote(rec.key())
+        ctx = lib.ensure(rec)
+        t0 = time.monotonic()
+        jax.block_until_ready(ctx.value["engine"].cache)
+        assert ctx.restore_seconds >= time.monotonic() - t0
 
     def test_restore_preserves_midstream_state(self, smol):
         """Demoting between megasteps and restoring must continue decoding
