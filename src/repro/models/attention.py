@@ -251,18 +251,20 @@ def _merge_rows(view: jax.Array, tail: jax.Array,
     holds new values for logical positions [start, start + Tb). Row b of
     the result equals view outside that span and tail inside it — prefix
     positions pass through untouched (bitwise), which is what keeps the
-    shared-prefill path exact."""
-    B, L = view.shape[:2]
-    Tb = tail.shape[1]
-    pos = jnp.arange(L, dtype=jnp.int32)[None, :]              # (1, L)
-    idx = jnp.clip(pos - starts[:, None], 0, Tb - 1)           # (B, L)
-    idxe = jnp.broadcast_to(
-        idx.reshape((B, L) + (1,) * (tail.ndim - 2)),
-        (B, L) + tail.shape[2:])
-    gathered = jnp.take_along_axis(tail.astype(view.dtype), idxe, axis=1)
-    in_tail = (pos >= starts[:, None]) & (pos < starts[:, None] + Tb)
-    return jnp.where(in_tail.reshape((B, L) + (1,) * (view.ndim - 2)),
-                     gathered, view)
+    shared-prefill path exact. starts (B,) lie in [0, L]; a span that runs
+    past L is cut there."""
+    L, Tb = view.shape[1], tail.shape[1]
+    # one contiguous write per row into a view padded by Tb positions, so a
+    # tail that runs past L is cut at L rather than shifted back by the
+    # start clamp of dynamic_update_slice
+    pad = [(0, 0), (0, Tb)] + [(0, 0)] * (view.ndim - 2)
+    padded = jnp.pad(view, pad)
+
+    def write(row, t, s):
+        return jax.lax.dynamic_update_slice_in_dim(row, t, s, axis=0)
+
+    merged = jax.vmap(write)(padded, tail.astype(view.dtype), starts)
+    return merged[:, :L]
 
 
 @jax.named_scope("attention")

@@ -5,6 +5,7 @@ prefix-aware routing/placement."""
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -13,6 +14,7 @@ from repro.core import ContextMode, PCMClient, PCMManager, load_context, \
     make_recipe
 from repro.core.scheduler import ContextAwareScheduler, Task
 from repro.models import build_model
+from repro.models.attention import _merge_rows
 from repro.serving import InferenceEngine, Request, RequestState, \
     SessionRouter
 from repro.serving.paged import PageAllocator, PrefixCache, pages_for
@@ -157,6 +159,45 @@ class TestRefcountInvariant:
         cache.evict(POOL, alloc)
         alloc.check(cache.pages())
         assert alloc.free_pages == POOL
+
+
+# -------------------------------------------------------- tail merge --
+def _overlay(view, tail, starts):
+    """Plain per-position overlay: the reference for ``_merge_rows``."""
+    out = view.copy()
+    L, Tb = view.shape[1], tail.shape[1]
+    for b, s in enumerate(starts):
+        for pos in range(s, min(s + Tb, L)):
+            out[b, pos] = tail[b, pos - s].astype(view.dtype)
+    return out
+
+
+@pytest.mark.parametrize("view_dt,tail_dt", [
+    ("bfloat16", "bfloat16"), ("float32", "float32"),
+    ("bfloat16", "float32")])
+@pytest.mark.parametrize("kv_heads", [32, 8])
+@pytest.mark.parametrize("tb", [1, 32, 256])
+def test_merge_rows_matches_plain_overlay(tb, kv_heads, view_dt, tail_dt):
+    """The tail lands at each row's start and stops at L; every other
+    position, the shared prefix and -0.0 included, passes through bitwise."""
+    L, D = 256, 4
+    # start 0, mid-page, L - Tb, and spans that run past L
+    starts = [0, 100, L - tb, L - tb + 1, L - 1, L - tb // 2 - 1]
+    starts = [min(max(s, 0), L - 1) for s in starts]
+    rng = np.random.RandomState(tb * kv_heads)
+    view = rng.randn(len(starts), L, kv_heads, D).astype(np.float32)
+    view[rng.rand(*view.shape) < 0.25] = -0.0
+    tail = rng.randn(len(starts), tb, kv_heads, D).astype(np.float32)
+    tail[rng.rand(*tail.shape) < 0.25] = -0.0
+    view_j = jnp.asarray(view, view_dt)
+    tail_j = jnp.asarray(tail, tail_dt)
+    got = np.asarray(jax.jit(_merge_rows)(
+        view_j, tail_j, jnp.asarray(starts, jnp.int32)))
+    want = _overlay(np.asarray(view_j), np.asarray(tail_j), starts)
+    assert got.dtype == want.dtype
+    bits = np.uint16 if got.dtype.itemsize == 2 else np.uint32
+    np.testing.assert_array_equal(got.view(bits), want.view(bits))
+    assert np.signbit(got[got == 0]).any()
 
 
 # ------------------------------------------------------ engine exactness --

@@ -2,14 +2,17 @@
 
 No chip is needed: the TPU compiler compiles against a described ``v5e:2x2``
 topology, which refuses what interpret mode accepts (block shapes that do
-not tile, kernels that exceed the scoped VMEM). Every compile must keep the
-kernel, so each asserts a ``tpu_custom_call`` in the compiled module.
+not tile, kernels that exceed the scoped VMEM). Every kernel compile must
+keep the kernel, so each asserts a ``tpu_custom_call`` in the compiled module;
+the shared-prefill merge, plain XLA, is held to per-row writes instead.
 
 The topology is described inside a fixture: only one process at a time may
 load the TPU library, so nothing here touches it at import or collection.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +23,7 @@ from repro.configs import get_config
 from repro.kernels.decode_attention import (flash_decode, paged_flash_decode,
                                             paged_mla_decode)
 from repro.kernels.flash_attention import flash_attention_bhsd
+from repro.models.attention import _merge_rows
 
 SLOTS, PAGE, NUM_PAGES, MAX_PAGES = 16, 64, 256, 8
 
@@ -91,3 +95,20 @@ def test_paged_mla_decode_compiles(one_chip):
              one_chip, ((SLOTS, H, R), dt), ((SLOTS, H, Dr), dt),
              ((NUM_PAGES + 1, PAGE, R), dt), ((NUM_PAGES + 1, PAGE, Dr), dt),
              ((SLOTS, MAX_PAGES), jnp.int32), ((SLOTS,), jnp.int32))
+
+
+def test_merge_rows_writes_rows_without_gather(one_chip):
+    """The shared-prefill merge at the smollm2-1.7b cell's shapes (32 slots,
+    a 256-position view, a 32-token tail bucket) compiles to per-row slice
+    writes. They access about 6x the bytes of view, tail and output (the
+    padded copy, the row loop, the slice back); an element-wise gather,
+    which reads one index per view element, accesses about 1100x."""
+    _, Hkv, D, dt = _gqa_widths()
+    view, tail = (32, 256, Hkv, D), (32, 32, Hkv, D)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in ((view, dt), (tail, dt), ((32,), jnp.int32))]
+    compiled = jax.jit(_merge_rows).lower(*args).compile()
+    assert not re.search(r"\bgather\(", compiled.as_text())
+    cost = compiled.cost_analysis()
+    moved = (2 * math.prod(view) + math.prod(tail)) * jnp.dtype(dt).itemsize
+    assert cost["bytes accessed"] < 8 * moved
