@@ -2,8 +2,9 @@
 
 After the window has closed and the program's state is freed, a sample
 of the answered claims, drawn from the seed and holding the longest
-prompt, is run once through the plain reference with the tokens the
-program served. For each served token the gap is the reference's best
+prompt, is run once through the plain reference of the configuration's
+architecture (``bench/architectures/``) with the tokens the program
+served. For each served token the gap is the reference's best
 logit at that position minus the reference's logit of the served token:
 0 where the program chose the reference's own greedy token, small where
 it chose a near-tie that rounding can flip, large where the served token
@@ -26,7 +27,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from bench import reference
+from bench import architectures, reference
 
 Answer = Tuple[List[int], List[int]]          # (prompt, served tokens)
 
@@ -47,7 +48,8 @@ def sample(answers: Sequence[Answer], n: int, seed: int) -> List[Answer]:
 
 def served_gaps(ref: np.ndarray, answers: Sequence[Answer]) -> np.ndarray:
     """Per served token: reference best logit minus the reference logit of
-    the served token (``ref`` is (N, R, V) from ``reference.logits_at``)."""
+    the served token (``ref`` is (N, R, V) from the architecture's
+    ``logits_at``)."""
     out = []
     for i, (_, g) in enumerate(answers):
         for j, t in enumerate(g):
@@ -69,8 +71,9 @@ def chosen_gaps(ref: np.ndarray, other: np.ndarray,
 
 def logit_gap(c: Dict, seed: int, picked: Sequence[Answer],
               control: bool = False) -> Dict[str, float]:
+    arch = architectures.of(c)
     tokens, lens, rows = reference.sequences(picked)
-    ref = reference.logits_at(c, seed, tokens, lens, rows)
+    ref = arch.logits_at(c, seed, tokens, lens, rows)
     gaps = served_gaps(ref, picked)
     top2 = np.sort(ref, axis=-1)[..., -2:]
     margins = np.concatenate([top2[i, :len(g), 1] - top2[i, :len(g), 0]
@@ -80,8 +83,8 @@ def logit_gap(c: Dict, seed: int, picked: Sequence[Answer],
            "near_tie_share": float(np.mean(margins < NEAR_TIE)),
            "tokens_compared": int(gaps.size)}
     if control:
-        low = reference.logits_at(c, seed, tokens, lens, rows,
-                                  precision="control")
+        low = arch.logits_at(c, seed, tokens, lens, rows,
+                             precision="control")
         cg = chosen_gaps(ref, low, picked)
         got.update(control_logit_gap=float(cg.max()),
                    control_mean_gap=float(cg.mean()),

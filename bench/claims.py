@@ -5,13 +5,18 @@ A copy of the program's synthetic claim world (``repro.data.fever``) and
 of its word-hash tokenizer (``repro.data.tokenizer``), so that the inputs
 of a run do not change when the program's data modules do. Claim ``i`` of
 seed ``s`` is the same on every machine: it hashes ``"s:i"``.
+
+Every seed gets the same sizes: claim ``i``'s domain, and so its number of
+words, is fixed by ``i``, and the shots' labels are one fixed set in the
+seed's order, so the template has one length. The seed draws the facts,
+the labels of the claims and the order of the shots' labels.
 """
 
 from __future__ import annotations
 
 import hashlib
 import random
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 LABELS = ("SUPPORTED", "REFUTED", "NOT ENOUGH INFO")
 BOS = 2
@@ -38,24 +43,33 @@ _TEMPLATES = {
 _UNKNOWN_SUBJECTS = ["zorblax", "quixel", "vantor", "mirelle", "koppen",
                      "drayune", "selvath", "ombrix"]
 
+_DOMAINS = sorted(_WORLD)
+
 # shots and claims come from disjoint index ranges of the seed's stream
 SHOT_BASE = 1 << 40
+# the shots' labels, 40/40/20 as the claims' are drawn, in the seed's order
+SHOT_LABELS = ("SUPPORTED",) * 3 + ("REFUTED",) * 3 + ("NOT ENOUGH INFO",) * 2
 
 
-def make_claim(index: int, seed: int) -> Dict[str, str]:
-    rng = random.Random(int.from_bytes(
-        hashlib.md5(f"{seed}:{index}".encode()).digest()[:8], "little"))
-    domain = rng.choice(sorted(_WORLD))
+def _rng(seed: int, key) -> random.Random:
+    return random.Random(int.from_bytes(
+        hashlib.md5(f"{seed}:{key}".encode()).digest()[:8], "little"))
+
+
+def make_claim(index: int, seed: int,
+               label: Optional[str] = None) -> Dict[str, str]:
+    """Claim ``index`` of ``seed``; with ``label``, one that has it."""
+    rng = _rng(seed, index)
+    domain = _DOMAINS[index % len(_DOMAINS)]
     facts = _WORLD[domain]
     a, b = rng.choice(facts)
     roll = rng.random()
-    if roll < 0.4:
-        label = "SUPPORTED"
-    elif roll < 0.8:
-        label = "REFUTED"
+    if label is None:
+        label = ("SUPPORTED" if roll < 0.4 else
+                 "REFUTED" if roll < 0.8 else "NOT ENOUGH INFO")
+    if label == "REFUTED":
         b = rng.choice([x for _, x in facts if x != b])
-    else:
-        label = "NOT ENOUGH INFO"
+    elif label == "NOT ENOUGH INFO":
         a = rng.choice(_UNKNOWN_SUBJECTS)
     return {"text": _TEMPLATES[domain].format(a=a, b=b), "label": label}
 
@@ -81,21 +95,29 @@ class ClaimStream:
         self.seed = int(seed)
         self.vocab_size = int(vocab_size)
         self.prompt = traffic["prompt"]
+        shots = int(traffic["shots"])
+        labels = [SHOT_LABELS[j % len(SHOT_LABELS)] for j in range(shots)]
+        _rng(self.seed, "shots").shuffle(labels)
         self._template = " ".join(
-            f"{self._ask(i)} {make_claim(i, self.seed)['label'].lower()} ."
-            for i in range(SHOT_BASE, SHOT_BASE + int(traffic["shots"])))
+            f"{self._ask(SHOT_BASE + j, label)} {label.lower()} ."
+            for j, label in enumerate(labels))
+        # the template's ids once: a prompt's words are the template's and
+        # then its claim's, so only the claim is hashed per prompt
+        self._template_ids = encode(self._template, self.vocab_size)
 
-    def _ask(self, index: int) -> str:
+    def _ask(self, index: int, label: Optional[str] = None) -> str:
         """The prompt for claim ``index``; ``{id}`` in it is the claim's
         row number, as a batch job labels its rows."""
-        return self.prompt.format(claim=make_claim(index, self.seed)["text"],
-                                  id=index)
+        claim = make_claim(index, self.seed, label)["text"]
+        return self.prompt.format(claim=claim, id=index)
 
     def text(self, index: int) -> str:
         return f"{self._template} {self._ask(index)}"
 
     def tokens(self, index: int) -> List[int]:
-        return encode(self.text(index), self.vocab_size)
+        """``encode(self.text(index))``, hashing only the claim's words."""
+        return self._template_ids + [token(w, self.vocab_size)
+                                     for w in self._ask(index).split()]
 
     def batch(self, start: int, n: int) -> List[List[int]]:
         return [self.tokens(i) for i in range(start, start + n)]

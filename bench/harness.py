@@ -3,7 +3,10 @@
 Everything that belongs to one configuration, traffic mix or per-layer
 metric is found by its name:
 
-  configuration   the ``file`` its ``configs`` entry names
+  configuration   the ``file`` its ``configs`` entry names; its
+                  ``architecture`` names the module in
+                  ``bench/architectures/<architecture>.py`` that holds
+                  the reference, weight layout and work counts
   traffic mix     ``bench/traffic/<traffic>.json``; its ``loop`` names the
                   task loop in ``bench/loops/<loop>.py``
   per-layer metric ``bench/metrics/<name>.py``, whose ``read(reading)``
@@ -20,13 +23,14 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib.util
 import json
 import os
 import shutil
 import sys
 import time
 from typing import Any, Dict, Optional
+
+from bench.modules import load_module
 
 SPEC = "BENCHMARK.json"
 
@@ -46,16 +50,6 @@ def entry(spec: Dict, key: str, name: str) -> Dict:
 
 def applies(metric: Dict, workload: str) -> bool:
     return "workloads" not in metric or workload in metric["workloads"]
-
-
-def load_module(path: str, name: str):
-    if not os.path.isfile(path):
-        raise FileNotFoundError(f"{path} not found for {name!r}")
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + name.replace("-", "_").replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def load_json(path: str) -> Dict:
@@ -165,7 +159,7 @@ def _recipe(c: Dict, seed: int):
     from repro.core import make_recipe
     from bench import model
     return make_recipe(f"{os.path.basename(c['file'])}.bench",
-                       model.build_context, (c["file"], int(seed)),
+                       model.build_context, (c["file"], int(seed), c["root"]),
                        **model.footprint(c))
 
 
@@ -174,13 +168,13 @@ def measure(root: str, workload: str, seed: int, seconds: float,
             control: bool = False) -> Dict:
     """One run of ``workload``. Returns the result object, with the
     numbers compared under ``compared`` (its last key)."""
-    from bench import check, claims, model, tracing
+    from bench import architectures, check, claims, model, tracing
     spec = load_spec(root)
     cell = entry(spec, "workloads", workload)
+    c = model.load_config(os.path.join(
+        root, entry(spec, "configs", cell["config"])["file"]), root)
     info = require_device(int(cell["chips"]))
     peaks = peaks_for(root, info["kind"])
-    c = model.load_config(os.path.join(
-        root, entry(spec, "configs", cell["config"])["file"]))
     traffic = load_json(os.path.join(root, "bench", "traffic",
                                      f"{cell['traffic']}.json"))
     loop = load_module(os.path.join(root, "bench", "loops",
@@ -195,7 +189,8 @@ def measure(root: str, workload: str, seed: int, seconds: float,
     from repro.core import ContextMode, PCMClient, PCMManager
     from repro.launch.compile_cache import configure_compile_cache
     configure_compile_cache()
-    model.program_config(c)             # refuse a mismatch before building
+    # refuse a mismatch before building
+    architectures.of(c).program_config(c)
     spill = os.path.join(root, ".pcm_spill")
     trace_dir = os.path.join(root, ".bench_trace")
     mgr = PCMManager(mode=ContextMode.FULL, n_workers=1, spill_dir=spill)

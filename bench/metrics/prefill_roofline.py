@@ -3,9 +3,10 @@ the engine's prefill executables (``_paged_prefill_impl``,
 ``_shared_prefill_impl``) in the trace. Work counts the prompt tokens each
 prefill computed (not its bucket's padding, not the prefix the cache
 served), their KV, and one read of every weight per prefill dispatch
-(``EngineStats.prefill_batches``)."""
+(``EngineStats.prefill_batches``), by the architecture's
+``prefill_cost``."""
 
-from bench import counts, tracing
+from bench import architectures, counts, tracing
 
 
 def read(r):
@@ -17,7 +18,7 @@ def read(r):
     calls = r.stats.get("prefill_batches", 0)
     if dev_s <= 0 or calls <= 0:
         return None
-    cost = counts.prefill_cost(
+    cost = architectures.of(r.config).prefill_cost(
         r.config, [(s, len(p)) for p, _, s in r.window["answers"]], calls)
     t, _ = counts.roofline_seconds(cost, r.peaks)
     return 100.0 * t / dev_s

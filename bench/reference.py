@@ -108,7 +108,8 @@ def logits_at(c: Dict, seed: int, tokens: np.ndarray, lengths: np.ndarray,
     ``precision`` is "reference" (float32) or "control"."""
     q = (_exact if precision == "reference"
          else lower_precision(c["torch_dtype"]))
-    make = bench_weights.layer_maker(c, seed, jnp.dtype(c["torch_dtype"]))
+    make = bench_weights.layer_maker(bench_weights.dense_layout(c), seed,
+                                     jnp.dtype(c["torch_dtype"]))
     N = tokens.shape[0]
     pad = -N % ROW_BLOCK
     tok = np.pad(tokens, ((0, pad), (0, 0)))

@@ -7,10 +7,11 @@ the leaf's name and the layer, so the whole tree (made on the device in
 one jitted call, in the type it is served in) and one layer made alone
 hold the same numbers.
 
-``LAYOUT`` names each leaf of the dense decoder's parameter tree (paths
-as ``/``-joined dict keys; ``layers/...`` leaves carry a leading layer
-axis) with the shape it has per layer and how it is drawn. A tree with a
-leaf that is not here, or without one that is, is refused.
+A layout (``layout(c)`` of the configuration's architecture, e.g.
+``dense_layout``) names each leaf of the parameter tree (paths as
+``/``-joined dict keys; ``layers/...`` leaves carry a leading layer axis)
+with the shape it has per layer and how it is drawn. A tree with a leaf
+that is not in the layout, or without one that is, is refused.
 """
 
 from __future__ import annotations
@@ -25,7 +26,10 @@ import numpy as np
 NORM_STD = 0.1      # norm scales are 1 + N(0, NORM_STD)
 
 
-def dense_layout(c: Dict) -> Dict[str, Tuple[Tuple[int, ...], int]]:
+Layout = Dict[str, Tuple[Tuple[int, ...], int]]
+
+
+def dense_layout(c: Dict) -> Layout:
     """path -> (per-layer shape, fan_in); fan_in 0 marks a norm scale."""
     d, f = c["hidden_size"], c["intermediate_size"]
     H, Hkv = c["num_attention_heads"], c["num_key_value_heads"]
@@ -67,20 +71,20 @@ def _path(kp) -> str:
     return "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in kp)
 
 
-def make_params(shapes, c: Dict, seed: int, dtype) -> Dict:
+def make_params(shapes, layout: Layout, layers: int, seed: int,
+                dtype) -> Dict:
     """The program's parameter tree (``shapes``, e.g. from
-    ``jax.eval_shape(model.init, ...)``), filled from the seed on the
-    default device in one jitted call."""
-    layout = dense_layout(c)
-    L = c["num_hidden_layers"]
+    ``jax.eval_shape(model.init, ...)``) of ``layers`` layers, filled
+    from the seed by ``layout`` on the default device in one jitted
+    call."""
     flat, tree = jax.tree_util.tree_flatten_with_path(shapes)
     paths = [_path(kp) for kp, _ in flat]
     if sorted(paths) != sorted(layout):
         raise ValueError(f"parameter tree {sorted(paths)} does not match "
-                         f"the dense layout {sorted(layout)}")
+                         f"the configuration's layout {sorted(layout)}")
     for p, (_, leaf) in zip(paths, flat):
         shape, _ = layout[p]
-        want = ((L,) + shape) if p.startswith("layers/") else shape
+        want = ((layers,) + shape) if p.startswith("layers/") else shape
         if tuple(leaf.shape) != want:
             raise ValueError(f"{p}: program shape {leaf.shape}, "
                              f"configuration shape {want}")
@@ -94,7 +98,7 @@ def make_params(shapes, c: Dict, seed: int, dtype) -> Dict:
                 leaves.append(jax.vmap(
                     lambda l, k=k, s=shape, fi=fan_in: _draw(
                         jax.random.fold_in(k, l), s, fi, dtype))(
-                    jnp.arange(L, dtype=jnp.uint32)))
+                    jnp.arange(layers, dtype=jnp.uint32)))
             else:
                 leaves.append(_draw(k, shape, fan_in, dtype))
         return jax.tree_util.tree_unflatten(tree, leaves)
@@ -102,11 +106,12 @@ def make_params(shapes, c: Dict, seed: int, dtype) -> Dict:
     return jax.jit(build)(seed_key(seed))
 
 
-def layer_maker(c: Dict, seed: int, dtype) -> Callable[[int], Dict]:
-    """``f(layer) -> {name: float32 array}`` for one decoder layer, each
-    value rounded through the served ``dtype`` first; ``f(-1)`` gives the
-    embedding table and the final norm."""
-    layout = dense_layout(c)
+def layer_maker(layout: Layout, seed: int,
+                dtype) -> Callable[[int], Dict]:
+    """``f(layer) -> {name: float32 array}`` for one layer of ``layout``,
+    each value rounded through the served ``dtype`` first; ``f(-1)``
+    gives the leaves outside the layers (the embedding table and the
+    final norm)."""
     key = seed_key(seed)
     names = [p for p in layout if p.startswith("layers/")]
     top = [p for p in layout if not p.startswith("layers/")]

@@ -48,6 +48,26 @@ def test_each_claim_prompt_ends_in_a_token_of_its_own():
     assert all(p[:n] == batch[0][:n] for p in batch)
 
 
+def test_every_seed_gets_the_same_prompt_sizes():
+    """The seed draws the prompts' words, not their lengths: the same
+    work for every seed, so seeds differ no more than runs of one seed.
+    A prompt's ids are its text's, though only its claim is hashed."""
+    from bench import claims
+    t = json.load(open(os.path.join(REPO, "bench", "traffic",
+                                    "factcheck.json")))
+    seeds = [1, 2, 2**31 + 5, 2**32 + 3, 4150000021, 4150000025]
+    sizes = set()
+    for seed in seeds:
+        s = claims.ClaimStream(t, seed, 49152)
+        batch = s.batch(0, 96) + s.batch(1 << 30, 32)
+        assert all(p == claims.encode(s.text(i), 49152) for i, p in zip(
+            list(range(96)) + list(range(1 << 30, (1 << 30) + 32)), batch))
+        sizes.add(tuple(len(p) for p in batch))
+    assert len(sizes) == 1
+    texts = {claims.ClaimStream(t, seed, 49152)._template for seed in seeds}
+    assert len(texts) == len(seeds)
+
+
 def test_a_compile_inside_the_window_is_counted(on_cpu):
     import jax
     import jax.numpy as jnp

@@ -6,26 +6,32 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import TINY, TINY_GQA
+from conftest import REPO, TINY, TINY_GQA
 
 
 def _config(c):
-    from bench import model
+    from bench import architectures
     c = dict(c)
     pad = c["vocab_pad_to"]
     c["padded_vocab"] = -(-c["vocab_size"] // pad) * pad
-    c["file"] = "tiny"
-    return c, model.program_config(c)
+    c["file"], c["root"] = "tiny", REPO
+    return c, architectures.of(c).program_config(c)
+
+
+def _params(m, c, seed):
+    from bench import weights
+    return weights.make_params(jax.eval_shape(m.init, jax.random.PRNGKey(0)),
+                               weights.dense_layout(c),
+                               c["num_hidden_layers"], seed, jnp.float32)
 
 
 @pytest.mark.parametrize("tiny", [TINY, TINY_GQA], ids=["mha", "gqa"])
 def test_reference_matches_program_forward(tiny):
     from repro.models import build_model
-    from bench import reference, weights
+    from bench import reference
     c, cfg = _config(tiny)
     m = build_model(cfg)
-    params = weights.make_params(jax.eval_shape(m.init, jax.random.PRNGKey(0)),
-                                 c, 7, jnp.float32)
+    params = _params(m, c, 7)
     rng = np.random.RandomState(0)
     toks = rng.randint(8, c["vocab_size"], size=(3, 40)).astype(np.int32)
     lens = np.array([40, 23, 9], np.int32)
@@ -43,9 +49,9 @@ def test_layer_by_layer_weights_equal_the_whole_tree():
     from bench import weights
     c, cfg = _config(TINY_GQA)
     m = build_model(cfg)
-    p = weights.make_params(jax.eval_shape(m.init, jax.random.PRNGKey(0)),
-                            c, 2**33 + 1, jnp.float32)
-    make = weights.layer_maker(c, 2**33 + 1, jnp.float32)
+    p = _params(m, c, 2**33 + 1)
+    make = weights.layer_maker(weights.dense_layout(c), 2**33 + 1,
+                               jnp.float32)
     for layer in range(c["num_hidden_layers"]):
         w = make(layer)
         np.testing.assert_array_equal(w["wk"], p["layers"]["attn"]["wk"][layer])
@@ -63,11 +69,10 @@ def test_engine_tokens_have_no_gap_and_a_wrong_token_has_one():
     from repro.models import build_model
     from repro.serving import InferenceEngine
     from repro.serving.request import Request
-    from bench import check, claims, weights
+    from bench import check, claims
     c, cfg = _config(TINY)
     m = build_model(cfg)
-    params = weights.make_params(jax.eval_shape(m.init, jax.random.PRNGKey(0)),
-                                 c, 3, jnp.float32)
+    params = _params(m, c, 3)
     eng = InferenceEngine(m, params, slots=4, cache_len=256,
                           prefill_buckets=(32, 256), megastep=8, paged=True)
     stream = claims.ClaimStream({"prompt": "claim : {claim} . answer :",
