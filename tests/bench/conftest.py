@@ -14,62 +14,88 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-TINY = {
-    "source": "tiny float32 shape of the dense decoder, for CPU tests",
-    "architecture": "dense_decoder",
-    "program_arch": "smollm2-1.7b",
-    "hidden_act": "silu",
-    "hidden_size": 64,
-    "intermediate_size": 128,
-    "num_hidden_layers": 2,
-    "num_attention_heads": 4,
-    "num_key_value_heads": 4,
-    "rms_norm_eps": 1e-05,
-    "rope_theta": 130000,
-    "tie_word_embeddings": True,
-    "vocab_size": 512,
-    "vocab_pad_to": 256,
-    "torch_dtype": "float32",
-    "serving": {"slots": 4, "cache_len": 256, "megastep": 8,
-                "prefill_buckets": [32, 256], "page_size": 64,
-                "kv_cache_dtype": "float32"},
-}
-TINY_GQA = dict(TINY, program_arch="granite-3-2b", num_key_value_heads=2,
-                vocab_size=515, rope_theta=10000.0,
-                num_hidden_layers=3)
+TINY_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "tiny")
 CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
 CPU_PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11,
              "hbm_bytes": 16e9}
-CELLS = [("tiny.factcheck", "tiny", "factcheck"),
-         ("tiny-gqa.factcheck", "tiny-gqa", "factcheck")]
 
 
-def make_root(tmp, limit: float = 1e-3) -> str:
-    """A root holding a copy of bench/ and a BENCHMARK.json over tiny
-    configurations, with the real spec's metrics."""
+def load_tinies(directory: str):
+    """The tiny configurations in ``directory`` (``<name>.json``, each a
+    configuration as ``bench.model.load_config`` reads it plus the
+    traffic mixes it ``rehearses``), by name: the configurations, and the
+    mixes each rehearses."""
+    configs, rehearses = {}, {}
+    for name in sorted(f[:-5] for f in os.listdir(directory)
+                       if f.endswith(".json")):
+        with open(os.path.join(directory, f"{name}.json")) as f:
+            configs[name] = json.load(f)
+        rehearses[name] = configs[name].pop("rehearses")
+    return configs, rehearses
+
+
+def tiny_cells(rehearses):
+    """(workload, configuration, traffic) of every tiny cell."""
+    return [(f"{n}.{t}", n, t) for n, ts in rehearses.items() for t in ts]
+
+
+TINIES, REHEARSES = load_tinies(TINY_DIR)
+TINY, TINY_GQA = TINIES["tiny"], TINIES["tiny-gqa"]
+CELLS = tiny_cells(REHEARSES)
+
+
+def _architecture(source: str, config: dict):
+    """The architecture a configuration's file names; None where the file
+    is not there, so that no tiny cell rehearses its cells."""
+    path = os.path.join(source, config["file"])
+    if not os.path.isfile(path):
+        return None
+    with open(path) as f:
+        return json.load(f).get("architecture")
+
+
+def make_root(tmp, limit: float = 1e-3, source: str = REPO) -> str:
+    """A root holding a copy of ``source``'s bench/ and a BENCHMARK.json
+    over the tiny configurations of its tests/bench/tiny/, with its spec's
+    metrics. A cell of the spec stands for its configuration's
+    architecture and its traffic, and is rehearsed by every tiny cell of
+    the same pair; a metric's list of cells becomes the tiny cells that
+    rehearse a cell on it, and a cell that none rehearses drops out."""
     root = str(tmp)
-    shutil.copytree(os.path.join(REPO, "bench"), os.path.join(root, "bench"),
+    shutil.copytree(os.path.join(source, "bench"),
+                    os.path.join(root, "bench"),
                     ignore=shutil.ignore_patterns("__pycache__"))
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+    with open(os.path.join(source, "BENCHMARK.json")) as f:
         spec = json.load(f)
-    for name, c in (("tiny", TINY), ("tiny-gqa", TINY_GQA)):
+    # from the repo, the loaded configurations, which a test may patch
+    tinies, rehearses = (TINIES, REHEARSES) if source == REPO else \
+        load_tinies(os.path.join(source, "tests", "bench", "tiny"))
+    cells = tiny_cells(rehearses)
+    for name, c in tinies.items():
         with open(os.path.join(root, "bench", "configs", f"{name}.json"),
                   "w") as f:
             json.dump(c, f)
-    spec["configs"] = [{"name": n, "source": "tests", "reduced": [],
-                        "file": f"bench/configs/{n}.json", "why": "tests"}
-                       for n in ("tiny", "tiny-gqa")]
-    spec["workloads"] = [{"name": w, "config": c, "traffic": t, "chips": 1,
-                          "why": "tests"} for w, c, t in CELLS]
-    # the tiny GQA cell rehearses the chip cell's traffic on a GQA shape
-    rename = {"smollm2-1.7b.factcheck": ["tiny.factcheck",
-                                         "tiny-gqa.factcheck"]}
+    by_pair = {}
+    for w, n, t in cells:
+        by_pair.setdefault((tinies[n]["architecture"], t), set()).add(w)
+    configs = {c["name"]: c for c in spec["configs"]}
+    rehearsed_by = {
+        w["name"]: by_pair.get((_architecture(source, configs[w["config"]]),
+                                w["traffic"]), set())
+        for w in spec["workloads"]}
     for m in spec["end_to_end"] + spec["per_layer"]:
         if "workloads" in m:
-            m["workloads"] = [t for w in m["workloads"] for t in rename[w]]
+            named = set().union(*(rehearsed_by.get(w, ())
+                                  for w in m["workloads"]))
+            m["workloads"] = [w for w, _, _ in cells if w in named]
+    spec["configs"] = [{"name": n, "source": "tests", "reduced": [],
+                        "file": f"bench/configs/{n}.json", "why": "tests"}
+                       for n in tinies]
+    spec["workloads"] = [{"name": w, "config": n, "traffic": t, "chips": 1,
+                          "why": "tests"} for w, n, t in cells]
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(spec, f)
-    for w, _, _ in CELLS:
+    for w, _, _ in cells:
         with open(os.path.join(root, "bench", "limits", f"{w}.json"),
                   "w") as f:
             json.dump({"logit_gap": {"limit": limit}}, f)

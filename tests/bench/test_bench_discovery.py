@@ -5,9 +5,11 @@ BENCHMARK.json, and no edit of a file that is already there."""
 import hashlib
 import json
 import os
+import shutil
 import time
 
-from conftest import TINY, TINY_GQA, make_root, with_fake_device
+from conftest import (CELLS, REPO, TINY, TINY_GQA, make_root,
+                      with_fake_device)
 
 READER = '''"""Claims answered in the window (a made-up per-layer metric)."""
 
@@ -33,8 +35,10 @@ def check(c):
 
 
 def _digests(root):
+    """sha256 of every file under ``root``'s bench/ and tests/bench/."""
     out = {}
-    for d, _, files in os.walk(os.path.join(root, "bench")):
+    for d, _, files in (w for top in ("bench", os.path.join("tests", "bench"))
+                        for w in os.walk(os.path.join(root, top))):
         for f in files:
             p = os.path.join(d, f)
             with open(p, "rb") as fh:
@@ -55,6 +59,13 @@ def _add_cell(root, spec, config, cfg, traffic):
     spec["configs"].append({"name": config, "source": "tests",
                             "file": f"bench/configs/{config}.json",
                             "reduced": [], "why": "tests"})
+    return _add_workload(spec, config, traffic)
+
+
+def _add_workload(spec, config, traffic):
+    """A cell of a configuration the spec has, named in each metric's
+    list of cells."""
+    workload = f"{config}.{traffic}"
     spec["workloads"].append({"name": workload, "config": config,
                               "traffic": traffic, "chips": 1,
                               "why": "tests"})
@@ -133,4 +144,59 @@ def test_new_architecture_needs_only_new_files(tmp_path, on_cpu,
     wanted = {m["name"] for m in spec["per_layer"]}
     assert set(traced["metrics"]) == wanted
     after = _digests(root)
+    assert {p: after[p] for p in before} == before
+
+
+def test_a_new_architecture_is_rehearsed_from_new_files_of_the_repo(
+        tmp_path, on_cpu, monkeypatch):
+    """From a checkout of the repo's own BENCHMARK.json, bench/ and
+    tests/bench/: a configuration of a new architecture with a cell of the
+    factcheck traffic and one of a traffic no tiny file rehearses, each
+    named in every metric's list, and one tiny file for the architecture.
+    The CPU fixtures rehearse the first cell on the tiny file, correct
+    plain and traced with every per-layer metric listed for it, drop the
+    second, and need no file that was there changed."""
+    from bench import tracing
+    monkeypatch.setattr(tracing, "read_events",
+                        with_fake_device(tracing.read_events))
+    src = str(tmp_path / "checkout")
+    for d in ("bench", os.path.join("tests", "bench")):
+        shutil.copytree(os.path.join(REPO, d), os.path.join(src, d),
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), src)
+    before = _digests(src)
+    spec_path = os.path.join(src, "BENCHMARK.json")
+    spec = json.load(open(spec_path))
+
+    with open(os.path.join(src, "bench", "architectures",
+                           "aliased_decoder.py"), "w") as f:
+        f.write(ARCHITECTURE)
+    real = json.load(open(os.path.join(src, "bench", "configs",
+                                       "smollm2-1.7b.json")))
+    rehearsed = _add_cell(src, spec, "aliased-1.7b",
+                          dict(real, architecture="aliased_decoder",
+                               aliased=True), "factcheck")
+    _add_workload(spec, "aliased-1.7b", "resume")
+    json.dump(spec, open(spec_path, "w"))
+    json.dump(dict(TINY_GQA, architecture="aliased_decoder", aliased=True,
+                   rehearses=["factcheck"]),
+              open(os.path.join(src, "tests", "bench", "tiny",
+                                "tiny-aliased.json"), "w"))
+
+    root = make_root(tmp_path / "root", source=src)
+    out = json.load(open(os.path.join(root, "BENCHMARK.json")))
+    w = "tiny-aliased.factcheck"
+    cells = {c for c, _, _ in CELLS} | {w}
+    assert {c["name"] for c in out["workloads"]} == cells
+    for m in out["end_to_end"] + out["per_layer"]:
+        if "workloads" in m:
+            assert set(m["workloads"]) == cells, m
+    assert all(c["traffic"] == "factcheck" for c in out["workloads"])
+
+    traced = _runs_correct(on_cpu, root, w)
+    wanted = {m["name"] for m in out["per_layer"] if on_cpu.applies(m, w)}
+    assert set(traced["metrics"]) == wanted
+    assert wanted == {m["name"] for m in spec["per_layer"]
+                      if on_cpu.applies(m, rehearsed)}
+    after = _digests(src)
     assert {p: after[p] for p in before} == before
