@@ -113,9 +113,21 @@ def _repeat_kv(k: jax.Array, n_heads: int) -> jax.Array:
     return jnp.repeat(k, n_heads // hkv, axis=2)
 
 
+def _layer_of(cache: jax.Array, layer: Optional[jax.Array]) -> jax.Array:
+    """Layer ``layer`` of a stacked cache; the cache itself without one."""
+    if layer is None:
+        return cache
+    return jax.lax.dynamic_index_in_dim(cache, layer, keepdims=False)
+
+
 def write_cache_row(cache: jax.Array, new_row: jax.Array, slot: jax.Array,
-                    mode: str) -> jax.Array:
+                    mode: str, layer: Optional[jax.Array] = None
+                    ) -> jax.Array:
     """Write one token per sequence into a (B, S, ...) cache at ``slot``.
+
+    With ``layer``, ``cache`` is stacked over layers, (L, B, S, ...), and
+    the rows land at ``[layer, b, slot_b]`` of it in place: nothing of the
+    other layers is read or written.
 
     mode="scatter": indexed .at[].set — one-row write, but on a TP mesh with
     a seq-sharded cache GSPMD resolves the scatter through an involuntary
@@ -127,6 +139,14 @@ def write_cache_row(cache: jax.Array, new_row: jax.Array, slot: jax.Array,
     cross-shard traffic. XLA fuses the select into the cache's donated
     buffer, so HBM traffic stays O(cache) read + masked write.
     """
+    if layer is not None:
+        if mode == "mask":
+            slab = write_cache_row(_layer_of(cache, layer), new_row, slot,
+                                   mode)
+            return jax.lax.dynamic_update_index_in_dim(cache, slab, layer, 0)
+        B = cache.shape[1]
+        return cache.at[layer, jnp.arange(B), slot].set(
+            new_row.astype(cache.dtype))
     B = cache.shape[0]
     if mode == "mask":
         S = cache.shape[1]
@@ -304,11 +324,14 @@ def attend_prefill_shared(p, x, cfg, *, positions, starts, kv_len,
 # --------------------------------------------------------------- decode ----
 @jax.named_scope("attention")
 def attend_decode(p, x, cfg, *, cache_k, cache_v, lengths,
-                  layer_window: int = 0, memory_kv=None):
+                  layer_window: int = 0, memory_kv=None, layer=None):
     """One-token decode. x (B,1,d); cache_k/v (B,Scache,Hkv,D); lengths (B,).
 
     Returns (y (B,1,d), new_cache_k, new_cache_v). SWA caches are ring
-    buffers (Scache == window); full caches write at ``lengths``.
+    buffers (Scache == window); full caches write at ``lengths``. With
+    ``layer``, cache_k/v are stacked over layers (L,B,Scache,Hkv,D): the
+    new row is written into layer ``layer`` in place, attention reads that
+    layer, and the whole stack is returned.
     """
     c = cdt(cfg)
     B = x.shape[0]
@@ -323,11 +346,14 @@ def attend_decode(p, x, cfg, *, cache_k, cache_v, lengths,
     q = apply_rope(q, cos, sin)
     k_new = apply_rope(k_new, cos, sin)
 
-    s_cache = cache_k.shape[1]
+    s_cache = cache_k.shape[-3]
     slot = lengths % s_cache if layer_window else jnp.minimum(
         lengths, s_cache - 1)
-    cache_k = write_cache_row(cache_k, k_new[:, 0], slot, cfg.kv_update)
-    cache_v = write_cache_row(cache_v, v_new[:, 0], slot, cfg.kv_update)
+    stack_k = write_cache_row(cache_k, k_new[:, 0], slot, cfg.kv_update,
+                              layer)
+    stack_v = write_cache_row(cache_v, v_new[:, 0], slot, cfg.kv_update,
+                              layer)
+    cache_k, cache_v = _layer_of(stack_k, layer), _layer_of(stack_v, layer)
 
     pos = jnp.arange(s_cache, dtype=jnp.int32)
     n_valid = jnp.minimum(lengths + 1, s_cache)
@@ -360,7 +386,7 @@ def attend_decode(p, x, cfg, *, cache_k, cache_v, lengths,
         out = grouped_attention_narrow(q * scale, cache_k, cache_v,
                                        valid)[:, :1]
     y = jnp.einsum("bshk,hkd->bsd", out.astype(c), p["wo"].astype(c))
-    return y, cache_k, cache_v
+    return y, stack_k, stack_v
 
 
 def _paged_write_row(pages: jax.Array, new_row: jax.Array,
@@ -635,10 +661,11 @@ def mla_prefill(p, x, cfg, *, positions, kv_len=None, return_kv: bool = False,
 
 
 @jax.named_scope("attention")
-def mla_decode(p, x, cfg, *, cache_ckv, cache_krope, lengths):
+def mla_decode(p, x, cfg, *, cache_ckv, cache_krope, lengths, layer=None):
     """Absorbed-matrix MLA decode: attention runs in the latent space.
 
-    cache_ckv (B,Sc,r); cache_krope (B,Sc,dr); x (B,1,d).
+    cache_ckv (B,Sc,r); cache_krope (B,Sc,dr); x (B,1,d). With ``layer``,
+    the caches are stacked over layers, as in ``attend_decode``.
     """
     m = cfg.mla
     c = cdt(cfg)
@@ -651,11 +678,13 @@ def mla_decode(p, x, cfg, *, cache_ckv, cache_krope, lengths):
 
     ckv_new, krope_new = _mla_latent(p, x, cfg)
     krope_new = apply_rope(krope_new[:, :, None, :], cos, sin)[:, :, 0, :]
-    slot = jnp.minimum(lengths, cache_ckv.shape[1] - 1)
-    cache_ckv = write_cache_row(cache_ckv, ckv_new[:, 0], slot,
-                                cfg.kv_update)
-    cache_krope = write_cache_row(cache_krope, krope_new[:, 0], slot,
-                                  cfg.kv_update)
+    slot = jnp.minimum(lengths, cache_ckv.shape[-2] - 1)
+    stack_ckv = write_cache_row(cache_ckv, ckv_new[:, 0], slot,
+                                cfg.kv_update, layer)
+    stack_krope = write_cache_row(cache_krope, krope_new[:, 0], slot,
+                                  cfg.kv_update, layer)
+    cache_ckv = _layer_of(stack_ckv, layer)
+    cache_krope = _layer_of(stack_krope, layer)
 
     # absorb W_uk into q: q_lat (B,1,H,r)
     q_lat = jnp.einsum("bshd,rhd->bshr", q_nope.astype(c), p["w_uk"].astype(c))
@@ -671,4 +700,4 @@ def mla_decode(p, x, cfg, *, cache_ckv, cache_krope, lengths):
     out_lat = jnp.einsum("bhst,btr->bshr", w, cache_ckv.astype(jnp.float32))
     out = jnp.einsum("bshr,rhd->bshd", out_lat.astype(c), p["w_uv"].astype(c))
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(c))
-    return y, cache_ckv, cache_krope
+    return y, stack_ckv, stack_krope

@@ -14,9 +14,10 @@ The public surface is a ``Model`` record of pure functions:
   prefill(params, tokens, lengths, cache) -> (last_logits (B,V), cache)
   decode_step(params, tokens (B,1), lengths, cache) -> (logits (B,V), cache)
 
-KV caches are stacked over layers and threaded through the layer scan as
-``xs``/``ys`` (scan stacking re-assembles the updated cache), so decode is a
-single fused XLA while-loop over layers.
+KV caches are stacked over layers. Prefill threads them through the layer
+scan as ``xs``/``ys`` (scan stacking re-assembles the written cache); decode
+carries the stacked cache through the scan and writes each layer's new row
+into it in place, so a decode step moves one row per layer, not the cache.
 """
 
 from __future__ import annotations
@@ -115,16 +116,19 @@ def dense_block_prefill_shared(p, x, cfg, *, positions, starts, kv_len,
     return x + apply_mlp(p["mlp"], h, cfg), kv
 
 
-def dense_block_decode(p, x, cfg, *, lengths, window, cache_kv):
+def dense_block_decode(p, x, cfg, *, lengths, window, cache_kv, layer=None):
+    """One-token block. With ``layer``, ``cache_kv`` is the stack over the
+    scanned layers and this block writes and reads its layer of it."""
     h = apply_norm(p["ln1"], x, cfg)
     if cfg.attention == "mla":
         a, ck, kr = attn.mla_decode(p["attn"], h, cfg, cache_ckv=cache_kv[0],
-                                    cache_krope=cache_kv[1], lengths=lengths)
+                                    cache_krope=cache_kv[1], lengths=lengths,
+                                    layer=layer)
         new_kv = (ck, kr)
     else:
         a, ck, cv = attn.attend_decode(p["attn"], h, cfg, cache_k=cache_kv[0],
                                        cache_v=cache_kv[1], lengths=lengths,
-                                       layer_window=window)
+                                       layer_window=window, layer=layer)
         new_kv = (ck, cv)
     x = x + a
     h = apply_norm(p["ln2"], x, cfg)
@@ -176,12 +180,13 @@ def _kv_cache_shapes(cfg, batch: int, cache_len: int, dtype):
             jnp.zeros((batch, s, cfg.n_kv_heads, hd), dtype))
 
 
-def shard_kv_cache(kv):
+def shard_kv_cache(kv, stacked: bool = False):
     """Decode KV caches: batch over data axes, cache-seq over model axis
-    (uniform across archs — independent of head-count divisibility)."""
-    if kv[0].ndim == 4:
-        return tuple(shard(c, "batch", "kv_seq", None, None) for c in kv)
-    return tuple(shard(c, "batch", "kv_seq", None) for c in kv)
+    (uniform across archs — independent of head-count divisibility).
+    ``stacked`` caches carry a leading, unsharded layer axis."""
+    lead = (None,) if stacked else ()
+    return tuple(shard(c, *lead, "batch", "kv_seq",
+                       *[None] * (c.ndim - len(lead) - 2)) for c in kv)
 
 
 def _write_prefill_kv(cache_kv, new_kv, window: int):
@@ -347,16 +352,17 @@ def build_decoder(cfg) -> Model:
                                        window=window, cache_kv=ckv)
             new_dense0.append(kv)
 
-        def body(x, xs):
-            layer_params, ckv = xs
-            ckv = shard_kv_cache(ckv)
-            x, new_kv = dense_block_decode(layer_params, x, cfg,
-                                           lengths=lengths, window=window,
-                                           cache_kv=ckv)
-            return x, shard_kv_cache(new_kv)
+        def body(carry, xs):
+            x, kv = carry
+            layer_params, i = xs
+            x, kv = dense_block_decode(
+                layer_params, x, cfg, lengths=lengths, window=window,
+                cache_kv=shard_kv_cache(kv, stacked=True), layer=i)
+            return (x, shard_kv_cache(kv, stacked=True)), None
 
-        x, layers_kv = layer_scan(body, x, (params["layers"],
-                                            cache["layers"]))
+        (x, layers_kv), _ = layer_scan(
+            body, (x, cache["layers"]),
+            (params["layers"], jnp.arange(n_scan, dtype=jnp.int32)))
         x = apply_norm(params["final_norm"], x, cfg)
         logits = unembed(params["embed"], x, cfg)[:, 0]
         new_cache = {"layers": layers_kv}

@@ -4,7 +4,8 @@ No chip is needed: the TPU compiler compiles against a described ``v5e:2x2``
 topology, which refuses what interpret mode accepts (block shapes that do
 not tile, kernels that exceed the scoped VMEM). Every kernel compile must
 keep the kernel, so each asserts a ``tpu_custom_call`` in the compiled module;
-the shared-prefill merge, plain XLA, is held to per-row writes instead.
+the shared-prefill merge, plain XLA, is held to per-row writes instead, and
+the decode loop to in-place row writes into the cache it carries.
 
 The topology is described inside a fixture: only one process at a time may
 load the TPU library, so nothing here touches it at import or collection.
@@ -20,6 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.models import build_model
 from repro.kernels.decode_attention import (flash_decode, paged_flash_decode,
                                             paged_mla_decode)
 from repro.kernels.flash_attention import flash_attention_bhsd
@@ -112,3 +114,66 @@ def test_merge_rows_writes_rows_without_gather(one_chip):
     cost = compiled.cost_analysis()
     moved = (2 * math.prod(view) + math.prod(tail)) * jnp.dtype(dt).itemsize
     assert cost["bytes accessed"] < 8 * moved
+
+
+def _while_bodies(hlo: str) -> str:
+    """The text of every computation a ``while`` body runs, fusions and
+    nested loops included."""
+    comps = dict(re.findall(r"^(?:ENTRY )?%(\S+) .*?\{\n(.*?)^\}", hlo,
+                            re.M | re.S))
+    todo = re.findall(r"\bwhile\(.*?\bbody=%([\w.\-]+)", hlo)
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo += re.findall(r"(?:calls|body|condition|to_apply)=%([\w.\-]+)",
+                               comps[name])
+    return "\n".join(comps[n] for n in seen)
+
+
+def test_decode_loop_writes_rows_into_the_carried_cache(one_chip):
+    """Three decode steps of smollm2-1.7b over the cell's cache (32 slots,
+    256 positions), in a ``while_loop`` as the megastep runs them. Each
+    layer writes its new K/V rows into the carried stack in place: no loop
+    body copies the stack, rewrites it whole, or cuts out a layer's slab.
+    The device keeps the carry (its 64-wide head dim padded to the 128-lane
+    tile) and little else: a per-step copy of the carry would double the
+    temporary bytes, and re-stacking the cache through the layer scan took
+    three times them."""
+    cfg = get_config("smollm2-1.7b")
+    model = build_model(cfg)
+    dt = jnp.dtype(cfg.compute_dtype)
+    B, S, L = 32, 256, cfg.n_layers
+    Hkv, D = cfg.n_kv_heads, cfg.resolved_head_dim
+
+    def loop(params, tokens, lengths):
+        cache = model.init_cache(B, S, dt)
+
+        def body(c):
+            step, tokens, lengths, cache = c
+            logits, cache = model.decode_step(params, tokens, lengths, cache)
+            tokens = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
+            return step + 1, tokens, lengths + 1, cache
+        return jax.lax.while_loop(lambda c: c[0] < 3, body,
+                                  (jnp.int32(0), tokens, lengths, cache))
+
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    compiled = jax.jit(loop).lower(
+        params, jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)).compile()
+    bodies = _while_bodies(compiled.as_text())
+    stack, slab = f"bf16[{L},{B},{S},{Hkv},{D}]", f"bf16[{B},{S},{Hkv},{D}]"
+    for line in bodies.splitlines():
+        out = re.match(r"\s*(?:ROOT )?%\S+ = (\S+?)\{.*?\} ([\w\-]+)\(",
+                       line)
+        if out is None:
+            continue
+        shape, op = out.groups()
+        assert not (shape == stack and op in ("copy", "dynamic-update-slice")
+                    ), line
+        assert not (shape == slab and op != "parameter"), line
+    carry = 2 * L * B * S * Hkv * (-(-D // 128) * 128) * dt.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * carry
