@@ -30,8 +30,9 @@ snapshot pool, spilling LRU snapshots to local disk through
 ``checkpoint/io`` — and the next task that needs it RESTORES instead of
 rebuilding: zero builder calls, zero XLA compiles, bit-identical greedy
 outputs, at transfer cost instead of minutes of startup. That delta is
-the paper's headline number; ``python -m benchmarks.run --only pcm``
-measures it for real (BENCH_pcm.json).
+the paper's headline number. ``tests/test_runtime.py`` checks the round
+trip (``TestEngineTierRoundTrip``); ``python3 bench/run.py`` measures
+speed on the chip.
 
 Migrating from the PR-0 decorator API:
 
@@ -72,9 +73,8 @@ INTERACTIVE turns jump ahead of queued BATCH turns (never preempting a
 running decode); BATCH tenants share capacity by deficit round-robin.
 The engine underneath admits new prefills continuously as slots free —
 an arrival waits at most one megastep for admission, not a whole wave
-drain. ``python -m benchmarks.run --only frontdoor`` measures
-continuous-vs-drain tokens/s and p50/p99 TTFT under an open-loop Poisson
-session load (BENCH_frontdoor.json).
+drain. ``tests/test_frontdoor.py`` and ``tests/test_serving.py`` check
+sheds, streams and mid-stream admission.
 
 Paged KV cache (``paged=True``). The contiguous slot cache pays
 ``slots x cache_len`` positions per leaf whether a slot holds 12 tokens
@@ -90,8 +90,8 @@ demotion/peer transfer ships only the live pages (``snapshot()`` splits
 GQA and MLA latents, routed through Pallas paged-decode kernels when
 ``cfg.use_kernels``); SSM/xLSTM state and sliding-window ring buffers
 silently keep the slot path (``engine.paged_fallback`` says why).
-``python -m benchmarks.run --only paged`` measures the session
-multiplier, decode parity and snapshot shrink (BENCH_paged.json).
+The ``test_paged_*`` tests of ``tests/test_serving.py`` check the session
+multiplier, decode parity and live-page snapshots.
 
 Copy-on-write prefix sharing (paged + ``prefix_sharing=True``, the
 default). High-throughput lightweight-LLM applications send the SAME
@@ -109,9 +109,8 @@ never blocks admission. Greedy outputs stay bit-identical to unshared
 prefill and warm paths still compile nothing. Above the engine,
 ``open_session(..., prefix_key=...)`` lanes template-mates onto the same
 engine and the scheduler's placement prefers a prefix-warm worker over
-an equally-warm cold one. ``python -m benchmarks.run --only prefix``
-measures the prefill shrink, TTFT win and session multiplier
-(BENCH_prefix.json).
+an equally-warm cold one. ``tests/test_prefix.py`` checks the parity,
+the prefill shrink and the session multiplier.
 
 Multi-host PCM (the socket transport). A LiveWorker can be a PROCESS on
 another node: the manager opens the transport (``manager.listen()``) and
@@ -131,9 +130,9 @@ reconciles lane failures), a ``kill -9``'d node is detected by socket
 EOF (or, for wedged links, a heartbeat monitor) and fed to the SAME
 preemption path as a reclaimed GPU, and the planner prices wire lanes
 in their own ``p2p:socket`` calibration namespace so a cold socket lane
-never inherits in-process memcpy history. ``python -m benchmarks.run
---only multihost`` measures the serialized-bootstrap-vs-cold-build gap
-with two real processes (BENCH_multihost.json).
+never inherits in-process memcpy history. ``tests/test_multihost.py``
+drives it with real worker processes: greedy parity, zero builder calls
+and zero true recompiles on a joiner.
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """

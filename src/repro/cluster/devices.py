@@ -2,7 +2,7 @@
 TPU generations this framework targets.
 
 The inference/startup cost models are deliberately simple and *calibrated*
-(see benchmarks/calibration.py) against the paper's measured quantities:
+against the paper's measured quantities (its RQ1 and RQ2 figures):
 a profile gives peak compute, HBM bandwidth, host-link bandwidth and disk
 read bandwidth; task times are derived, then two global calibration knobs
 (framework warm-up seconds, per-inference overhead) are fit so the RQ1
@@ -78,8 +78,8 @@ def cluster_census() -> List[str]:
 class CostModel:
     """Calibration knobs shared across profiles.
 
-    Fit against the paper's RQ1/RQ2 measurements (see
-    benchmarks/rq1_context_levels.py): full-context 2.9 ks @ bs=100 pins
+    Fit against the paper's RQ1/RQ2 figures (tests/test_cluster_sim.py
+    pins the shapes they give): full-context 2.9 ks @ bs=100 pins
     (inference_overhead, task_overhead); partial-vs-full pins the disk->GPU
     load; agnostic-vs-partial pins the shared-FS fetch, whose conda-env
     portion pays a small-file metadata penalty (the paper cites metaFS

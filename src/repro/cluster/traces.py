@@ -4,9 +4,8 @@ open-loop arrival generator for the streaming front door.
 A capacity trace is ``capacity_fn(t) -> list[profile_name]`` — the
 opportunistic slots the cluster exposes at time t (what the TaskVine
 factory sees). ``poisson_sessions`` is the LOAD side of the same story:
-deterministic open-loop session arrival times, shared by the frontdoor
-benchmark and simulator-backed session tests so both replay the identical
-workload.
+deterministic open-loop session arrival times, so a live run and a
+simulator-backed one can replay the identical workload.
 """
 
 from __future__ import annotations
@@ -91,9 +90,9 @@ def poisson_sessions(rate: float, duration: float,
                      seed: int = 0) -> List[float]:
     """Open-loop Poisson session arrivals: sorted arrival times in
     ``[0, duration)`` with exponential inter-arrival gaps of mean
-    ``1/rate`` (arrivals/second). Deterministic in ``seed`` — the
-    frontdoor benchmark and the simulator backend replay the exact same
-    schedule. Open-loop means arrivals never wait for service: this is the
+    ``1/rate`` (arrivals/second). Deterministic in ``seed`` — a live
+    driver and the simulator backend replay the exact same schedule.
+    Open-loop means arrivals never wait for service: this is the
     load model that exposes queueing (and shedding) behaviour, unlike
     closed-loop drivers whose offered load collapses under slowdown."""
     if rate <= 0:

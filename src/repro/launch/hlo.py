@@ -1,10 +1,9 @@
-"""HLO text analysis: collective-byte accounting for the roofline.
+"""HLO text analysis: collective-byte accounting.
 
 Parses compiled (post-GSPMD) HLO and sums the operand bytes of every
 all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute.
-Collectives inside while-loop bodies are counted once by this parse —
-callers account for trip counts by compiling UNROLLED probe configs and
-extrapolating per-layer (see launch/roofline.py).
+Collectives inside while-loop bodies are counted once by this parse, not
+once per trip.
 """
 
 from __future__ import annotations
@@ -18,9 +17,6 @@ _DTYPE_BYTES = {
     "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8, "c64": 8,
     "c128": 16, "f8e4m3fn": 1, "f8e5m2": 1,
 }
-
-_COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
-                "collective-permute")
 
 _SHAPE_RE = re.compile(r"\b([a-z0-9]+)\[([0-9,]*)\]")
 _OP_RE = re.compile(
@@ -43,7 +39,7 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
     Post-optimization HLO prints operands without shapes, so we size each
     collective by its RESULT shape: exact for all-reduce / all-to-all /
     collective-permute, received-bytes for all-gather, and sent-bytes/shards
-    for reduce-scatter (conservative; noted in EXPERIMENTS.md)."""
+    for reduce-scatter (conservative)."""
     out: Dict[str, int] = defaultdict(int)
     for line in hlo_text.splitlines():
         m = _OP_RE.search(line)
@@ -58,40 +54,3 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
         out["total"] += nbytes
     return dict(out)
 
-
-def count_ops(hlo_text: str, opname: str) -> int:
-    return len(re.findall(rf"\b{re.escape(opname)}\b", hlo_text))
-
-
-def memory_stats(compiled) -> Dict[str, float]:
-    """Best-effort extraction from compiled.memory_analysis()."""
-    ma = None
-    try:
-        ma = compiled.memory_analysis()
-    except Exception:
-        return {}
-    out = {}
-    for field in ("argument_size_in_bytes", "output_size_in_bytes",
-                  "temp_size_in_bytes", "generated_code_size_in_bytes",
-                  "alias_size_in_bytes", "serialized_size_in_bytes"):
-        v = getattr(ma, field, None)
-        if v is not None:
-            out[field] = float(v)
-    if not out and ma is not None:
-        out["repr"] = str(ma)[:2000]
-    return out
-
-
-def cost_stats(lowered_or_compiled) -> Dict[str, float]:
-    try:
-        ca = lowered_or_compiled.cost_analysis()
-    except Exception:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    out = {}
-    for k in ("flops", "bytes accessed", "transcendentals",
-              "optimal_seconds"):
-        if k in ca:
-            out[k.replace(" ", "_")] = float(ca[k])
-    return out

@@ -1,4 +1,4 @@
-"""Step-function builders for launchers and the dry-run.
+"""Step-function builders for the launchers.
 
 For a (config, shape-suite, mesh) cell this produces the jit-wrapped
 function with full in/out shardings plus abstract (ShapeDtypeStruct)
@@ -8,7 +8,7 @@ arguments — everything ``.lower().compile()`` needs, with zero allocation.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -138,29 +138,3 @@ def build_cell(cfg: ModelConfig, suite: ShapeSuite, mesh,
         fn, args = build_decode_cell(cfg, suite, mesh, rules)
     return fn, args, rules
 
-
-# --------------------------------------------------- analysis variants -----
-def probe_config(cfg: ModelConfig, units: int) -> ModelConfig:
-    """A pattern-preserving shallow config (for per-layer HLO probes)."""
-    if cfg.family == "hybrid" and cfg.shared_attn_every:
-        n = units * cfg.shared_attn_every
-    elif cfg.cross_attn_every:
-        n = units * cfg.cross_attn_every
-    elif cfg.family == "ssm" and cfg.ssm.slstm_every:
-        n = units * cfg.ssm.slstm_every
-    else:
-        n = units + cfg.moe.first_dense_layers
-    over = {"n_layers": n}
-    if cfg.family == "audio":
-        over["n_encoder_layers"] = max(1, units)
-    return dataclasses.replace(cfg, **over)
-
-
-def pattern_unit(cfg: ModelConfig) -> int:
-    if cfg.family == "hybrid" and cfg.shared_attn_every:
-        return cfg.shared_attn_every
-    if cfg.cross_attn_every:
-        return cfg.cross_attn_every
-    if cfg.family == "ssm" and cfg.ssm.slstm_every:
-        return cfg.ssm.slstm_every
-    return 1

@@ -1,8 +1,7 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> ...``
 
-Runs real steps on the local device(s) with reduced configs (CPU container)
-or, with --production-lower, just lowers/compiles the full config against
-the production mesh (no execution — that path is the dry-run's job).
+Runs real steps on the local device(s), with the reduced config unless
+``--full-config`` asks for the published one.
 """
 
 from __future__ import annotations
@@ -11,7 +10,7 @@ import argparse
 
 import jax
 
-from repro.configs import SHAPES, get_config, get_reduced_config
+from repro.configs import get_config, get_reduced_config
 from repro.data import PipelineConfig, batches
 from repro.launch.compile_cache import configure_compile_cache
 from repro.models import build_model

@@ -16,9 +16,6 @@ batch to finish.  Greedy outputs are bit-identical regardless of what
 shares the batch (see ``test_batching_invariance``), so continuous
 admission changes *when* requests run, never *what* they generate, and it
 reuses the same AOT executables — zero extra compiles.
-``admission="drain"`` keeps the legacy drain-between-waves behaviour (all
-active slots run to completion before the next wave admits); it exists as
-the measured baseline for the front-door benchmark, not for serving.
 
 **Admission order.**  ``submit`` maintains a priority queue: a request with
 higher ``Request.priority`` (e.g. an interactive-SLO session turn from the
@@ -320,15 +317,10 @@ class InferenceEngine:
                  megastep: int = 1,
                  decode_buckets: Optional[Sequence[int]] = None,
                  max_stop_tokens: int = 4,
-                 admission: str = "continuous",
                  paged: bool = False,
                  page_size: int = 64,
                  num_pages: Optional[int] = None,
                  prefix_sharing: bool = True):
-        if admission not in ("continuous", "drain"):
-            raise ValueError(f"admission must be 'continuous' or 'drain', "
-                             f"got {admission!r}")
-        self.admission = admission
         self._donate_cache = bool(donate_cache)
         self.model = model
         self.cfg = model.cfg
@@ -1260,7 +1252,6 @@ class InferenceEngine:
             "cache_dtype": str(np.dtype(self._cache_dtype)),
             "megastep": self.megastep,
             "max_stop_tokens": self.max_stop_tokens,
-            "admission": self.admission,
             "donate_cache": self._donate_cache,
             "paged": self._paged,
             "page_size": self.page_size,
@@ -1338,14 +1329,12 @@ class InferenceEngine:
     def step(self) -> List[Request]:
         """One scheduling step: admit queued prefills into free slots, then
         one decode megastep (up to K tokens) for all active slots. Returns
-        finished requests. In ``drain`` mode admission additionally waits
-        for the whole active set to finish."""
+        finished requests."""
         self._require_resident()
         finished: List[Request] = []
         with jax.profiler.TraceAnnotation("engine.step",
                                           step=self.stats.steps):
-            if self.queue and self.free_slots and (
-                    self.admission == "continuous" or not self.active):
+            if self.queue and self.free_slots:
                 with jax.profiler.TraceAnnotation("engine.admit"):
                     finished.extend(self._admit_wave())
             if self.active:
@@ -1707,10 +1696,7 @@ class InferenceEngine:
         t0 = time.monotonic()
         if self._prefix_cache is not None:
             self._decode_cow()
-        # a drain engine never admits mid-batch, so freeing a slot early
-        # cannot help anyone — the loop runs its full K
-        has_queue = jnp.asarray(bool(self.queue)
-                                and self.admission == "continuous")
+        has_queue = jnp.asarray(bool(self.queue))
         if self._paged:
             self.stats.live_pages = self._alloc.live_pages
             npages = self._decode_npages()
@@ -1816,7 +1802,6 @@ class InferenceEngine:
         return {
             "active": len(self.active), "queued": len(self.queue),
             "free_slots": len(self.free_slots),
-            "admission": self.admission,
             "offloaded": self.offloaded,
             "cache_bytes": cap,
             "capacity_bytes": cap,
@@ -1866,7 +1851,6 @@ def engine_from_wire(rec: Dict) -> "InferenceEngine":
         megastep=int(rec["megastep"]),
         decode_buckets=tuple(rec["decode_buckets"]),
         max_stop_tokens=int(rec["max_stop_tokens"]),
-        admission=rec.get("admission", "continuous"),
         paged=bool(rec.get("paged", False)),
         page_size=int(rec.get("page_size", 64)),
         num_pages=int(num_pages) if num_pages is not None else None,

@@ -1,5 +1,5 @@
 """train_step factory: chunked cross-entropy, microbatch gradient
-accumulation, remat — the function the dry-run lowers for ``train_*`` cells.
+accumulation, remat — the function ``build_cell`` lowers for ``train_*`` cells.
 
 Memory notes (why chunked CE): full logits for train_4k on qwen3-moe would
 be (16, 4096, 151936) per device — tens of GB. The loss contracts hidden

@@ -1,7 +1,7 @@
 import os
 
-# Tests run on the single real CPU device. (The dry-run forces 512 fake
-# devices itself, in a subprocess — never here.)
+# Tests run on the single real CPU device. (A test that needs several
+# fake devices forces them in a subprocess — never here.)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import sys

@@ -1,4 +1,4 @@
-"""Module-level builders/tasks for multi-host tests and benchmarks.
+"""Module-level builders/tasks for multi-host tests.
 
 Everything a recipe or task carries across the process boundary must be
 picklable by reference — lambdas and closures die at the socket. The
@@ -57,34 +57,6 @@ def generate_task(prompts, max_new_tokens: int = 6):
     return out, {"compiles": st.compiles,
                  "aot_cache_hits": st.aot_cache_hits,
                  "builder": False}
-
-
-def probe_task(prompts, max_new_tokens: int = 6):
-    """``generate_task`` plus provenance: the worker process pid (so a
-    multi-node benchmark can attribute each result to the node that ran
-    it) and the engine's true-XLA compile wall seconds (cache hits cost
-    none — the warm-vs-cold split the multihost bench reports)."""
-    import os
-    from repro.core.library import current_context
-    eng = current_context()["engine"]
-    out = eng.generate(prompts, max_new_tokens=max_new_tokens)
-    st = eng.stats
-    return os.getpid(), out, {"compiles": st.compiles,
-                              "aot_cache_hits": st.aot_cache_hits,
-                              "compile_seconds": eng.compile_seconds}
-
-
-def slow_probe_task(prompts, seconds: float = 0.4, max_new_tokens: int = 6):
-    """``probe_task`` with a floor on task duration, so a joiner-storm
-    benchmark keeps the warm donor busy long enough for the cold joiner
-    to bootstrap and claim a share of the queue."""
-    import time
-    time.sleep(seconds)
-    return probe_task(prompts, max_new_tokens=max_new_tokens)
-
-
-def noop_task():
-    return "ok"
 
 
 class MHSplitEngine:

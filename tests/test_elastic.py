@@ -253,6 +253,7 @@ class TestCostChooser:
         src, _, _ = s._choose_source(self.R, s.workers["cold"], 1.0,
                                      commit=False)
         assert src == FetchSource.PEER
+        assert src == s.rung_costs(self.R, "cold", 1.0)[0][0]   # cheapest
         # a measured completion calibrates the peer path SLOW: 100 s for
         # the template transfer
         plan = fast.peer_plan(self.R.transfer_bytes, {"donor"}, 1.0)
@@ -260,6 +261,7 @@ class TestCostChooser:
         src, plan, _ = s._choose_source(self.R, s.workers["cold"], 200.0,
                                         commit=False)
         assert src == FetchSource.DISK
+        assert src == s.rung_costs(self.R, "cold", 200.0)[0][0]
         # and the committed fetch records the same decision
         act = s._fetch(self.R, s.workers["cold"], 200.0)
         assert act.source == FetchSource.DISK

@@ -396,6 +396,11 @@ class TestNodeProcesses:
             mirC = mgr.workers["nodeC"].library
             assert mirC.builder_calls == 0     # never built: wire bootstrap
             assert mirC.peer_installs >= 1
+            assert mirC.peer_install_seconds > 0   # the install was timed
+            # the wire transfer calibrates the socket lane, and only it
+            cal = mgr.planner.calibration()
+            assert cal["p2p:socket"] is not None
+            assert cal["p2p:memcpy"] is None
             out = mgr.submit(H.checksum_task, recipe=rec).result(timeout=60)
             assert out == expect
         finally:

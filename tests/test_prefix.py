@@ -243,6 +243,27 @@ class TestSharedPrefillExactness:
         assert [first.generated] + [r.generated for r in rest] == want
         assert eng.stats.prefix_hits == 4
 
+    def test_sharing_admits_more_sessions_at_fixed_pool(self, smol):
+        """At the same page pool, hitters reserve only their unshared
+        pages, so the sharing engine runs >= 1.5x the concurrent sessions
+        of the unshared one, with the same greedy tokens."""
+        cfg, model, params = smol
+        ps = shared_prompts(cfg, 6, prefix_len=40, seed=4)
+        peak, outs = {}, {}
+        for sharing in (False, True):
+            eng = paged_engine(model, params, sharing=sharing, slots=6,
+                               num_pages=14)
+            reqs = [eng.submit(Request(prompt=list(p), max_new_tokens=6))
+                    for p in ps]
+            peak[sharing] = 0
+            while eng.has_work():
+                eng.step()
+                peak[sharing] = max(peak[sharing], len(eng.active))
+            outs[sharing] = [r.generated for r in reqs]
+        assert outs[True] == outs[False]
+        assert peak[False] >= 1
+        assert peak[True] >= 1.5 * peak[False], peak
+
     def test_zero_warm_compiles(self, smol):
         cfg, model, params = smol
         ps = shared_prompts(cfg, 4, seed=5)

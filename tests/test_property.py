@@ -71,7 +71,7 @@ def test_scheduler_liveness_under_random_events(events, seed):
         workers_running = [w for w, _ in s.running.values()]
         assert len(workers_running) == len(set(workers_running))
     # drain: add a worker and finish everything
-    s.on_worker_join("drain", t + 1)
+    s.on_worker_join("closer", t + 1)
     guard = 0
     while not s.all_done():
         guard += 1

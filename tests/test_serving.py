@@ -113,6 +113,19 @@ def test_megastep_parity_greedy(smol):
         "stop token never fired — test is vacuous"
 
 
+def test_megastep_fuses_many_tokens_per_dispatch(smol):
+    """A K=32 megastep must amortize its dispatch over many decode tokens:
+    the device loop, not the host, carries the decode."""
+    cfg, model, params = smol
+    eng = InferenceEngine(model, params, slots=4, cache_len=64,
+                          prefill_buckets=(16,), megastep=32)
+    eng.generate(prompts(cfg, 8, seed=41), max_new_tokens=32)
+    st = eng.stats
+    assert st.megasteps > 0
+    assert st.decode_tokens / st.megasteps >= 8, \
+        (st.decode_tokens, st.megasteps)
+
+
 def test_masked_slots_cache_unchanged(smol):
     """Free slots' cache rows must be bit-for-bit unchanged by megasteps."""
     cfg, model, params = smol
@@ -195,57 +208,48 @@ def test_megastep_prefix_buckets_parity(smol):
            ("megastep", 8, 64, False) in bucketed._exe
 
 
-def test_drain_vs_continuous_greedy_parity(smol):
-    """Mid-stream admission (continuous) must not change any request's
-    greedy output vs drain-between-waves: batching invariance extended to
-    the admission policy."""
+def test_midstream_admission_greedy_parity(smol):
+    """Admitting prefills into a live wave must not change any request's
+    greedy output: each equals what its prompt generates alone on a fresh
+    engine. Batching invariance extended to mid-stream admission."""
     cfg, model, params = smol
     ps = prompts(cfg, 7, seed=23)
-    outs = {}
-    for mode in ("continuous", "drain"):
-        eng = InferenceEngine(model, params, slots=2, cache_len=64,
-                              prefill_buckets=(16,), megastep=4,
-                              admission=mode)
-        # two-phase arrival: the second batch lands while the first is
-        # mid-decode, so continuous admits into a live wave
-        reqs = [eng.submit(Request(prompt=list(p), max_new_tokens=6))
-                for p in ps[:3]]
-        eng.step()
-        reqs += [eng.submit(Request(prompt=list(p), max_new_tokens=6))
-                 for p in ps[3:]]
-        eng.run_to_completion()
-        outs[mode] = [r.generated for r in reqs]
-    assert outs["continuous"] == outs["drain"]
-    with pytest.raises(ValueError):
-        InferenceEngine(model, params, slots=1, cache_len=32,
-                        admission="bogus")
+    kw = dict(slots=2, cache_len=64, prefill_buckets=(16,), megastep=4)
+    eng = InferenceEngine(model, params, **kw)
+    eng.warm_executables()
+    warm = eng.stats.compiles
+    # two-phase arrival: the second batch lands while the first is
+    # mid-decode, so it is admitted into a live wave
+    reqs = [eng.submit(Request(prompt=list(p), max_new_tokens=6))
+            for p in ps[:3]]
+    eng.step()
+    reqs += [eng.submit(Request(prompt=list(p), max_new_tokens=6))
+             for p in ps[3:]]
+    eng.run_to_completion()
+    assert eng.stats.compiles == warm
+    fresh = InferenceEngine(model, params, **kw)
+    alone = [fresh.generate([p], max_new_tokens=6)[0] for p in ps]
+    assert [r.generated for r in reqs] == alone
 
 
 def test_continuous_admits_on_slot_free(smol):
     """A queued prefill must be admitted the megastep after a slot frees —
-    not after the whole wave drains."""
+    not after the whole wave finishes."""
     cfg, model, params = smol
     ps = prompts(cfg, 3, seed=29)
-
-    def run(mode):
-        eng = InferenceEngine(model, params, slots=2, cache_len=64,
-                              prefill_buckets=(16,), megastep=2,
-                              admission=mode)
-        eng.submit(Request(prompt=list(ps[0]), max_new_tokens=2))   # short
-        eng.submit(Request(prompt=list(ps[1]), max_new_tokens=16))  # long
-        eng.submit(Request(prompt=list(ps[2]), max_new_tokens=4))   # queued
-        overlapped = False
-        while eng.has_work():
-            eng.step()
-            snap = eng.snapshot()
-            if snap["queued"] == 0 and snap["active"] == 2:
-                overlapped = True       # 3rd admitted while long one runs
-        return overlapped
-
-    assert run("continuous"), \
+    eng = InferenceEngine(model, params, slots=2, cache_len=64,
+                          prefill_buckets=(16,), megastep=2)
+    eng.submit(Request(prompt=list(ps[0]), max_new_tokens=2))   # short
+    eng.submit(Request(prompt=list(ps[1]), max_new_tokens=16))  # long
+    eng.submit(Request(prompt=list(ps[2]), max_new_tokens=4))   # queued
+    overlapped = False
+    while eng.has_work():
+        eng.step()
+        snap = eng.snapshot()
+        if snap["queued"] == 0 and snap["active"] == 2:
+            overlapped = True       # 3rd admitted while long one runs
+    assert overlapped, \
         "continuous admission never overlapped the queued request"
-    assert not run("drain"), \
-        "drain admitted mid-wave — it is not a drain baseline"
 
 
 def test_streaming_token_callbacks(smol):
@@ -510,9 +514,11 @@ def test_paged_offload_restore_midstream(smol):
             for p in ps]
     done = list(eng.step()) + list(eng.step())
     assert eng.active, "offload must happen mid-stream"
+    live_b = eng.snapshot()["live_bytes"]
     host = eng.offload_device_state()
     live_nbytes = sum(np.asarray(x).nbytes for x in
                       jax.tree_util.tree_leaves(host["cache"]))
+    assert live_nbytes == live_b
     cap = eng.num_pages * sum(
         np.asarray(x).nbytes for x in jax.tree_util.tree_leaves(
             model.init_cache(1, eng.page_size, jnp.float32)))

@@ -358,6 +358,57 @@ class TestStreamedRestore:
             restore_context(snap, "r0", spill_store=store, streamed=True)
 
 
+# ------------------------------------------------------- modeled storm --
+class TestModeledStorm:
+    """The same joiner storm through the simulator's production scheduler
+    under two planners: chunk-streamed, striped transfers against one
+    monolithic single-donor transfer (chunk >= payload, width 1, which the
+    pipeline formula degenerates to the sum of stages)."""
+    DONORS, JOINERS = 2, 4
+
+    def _storm(self, streamed: bool):
+        from repro.cluster.simulator import modeled_fetch_seconds
+        from repro.core.backend import SimulatorBackend
+
+        class FetchProbe(SimulatorBackend):
+            """Records the modeled seconds each bootstrap fetch takes."""
+
+            def __init__(self, *a, **kw):
+                super().__init__(*a, **kw)
+                self.fetch_seconds = {}
+
+            def _start_fetch(self, a):
+                self.fetch_seconds.setdefault(a.worker_id, []).append(
+                    modeled_fetch_seconds(a, self.profiles[a.worker_id],
+                                          self.cost, dict(self._stats)))
+                super()._start_fetch(a)
+
+        if streamed:
+            be = FetchProbe(n_workers=self.DONORS,
+                            planner=TransferPlanner(), donor_wait=True)
+        else:
+            be = FetchProbe(n_workers=self.DONORS,
+                            planner=TransferPlanner(chunk_bytes=1 << 62),
+                            donor_wait=True, stripe_width=1)
+        rec = make_recipe("storm.model", lambda: None)  # paper footprints
+        be.warm_up(rec)
+        futs = [be.submit(lambda: None, recipe=rec, n_items=4)
+                for _ in range(10 * (self.DONORS + self.JOINERS))]
+        joiners = [be.add_worker() for _ in range(self.JOINERS)]
+        for f in futs:
+            be.wait(f, timeout=300)
+        boots = {w: sum(v) for w, v in be.fetch_seconds.items()
+                 if w in joiners}
+        return boots, [d.source for d in be.fetch_history(rec)]
+
+    def test_streamed_striped_bootstrap_beats_monolithic(self):
+        mono, mono_src = self._storm(streamed=False)
+        streamed, streamed_src = self._storm(streamed=True)
+        assert len(mono) == len(streamed) == self.JOINERS
+        assert set(mono_src) == set(streamed_src) == {FetchSource.PEER}
+        assert sum(mono.values()) >= 1.5 * sum(streamed.values())
+
+
 # -------------------------------------------------- planner flow hygiene --
 class TestPlannerFailedFlows:
     NB = 10 * GB
